@@ -1,8 +1,9 @@
 """Benchmark: mesh-sharded serving — one sharded dispatch, per-shard aging.
 
-Must own its process: it fakes 8 host devices via ``XLA_FLAGS`` *before*
-jax initialises (run ``PYTHONPATH=src python -m benchmarks.mesh_bench``;
-``benchmarks.run --only mesh`` shells out here for the same reason).
+On a one-device host it must own its process: it fakes 8 host devices via
+``XLA_FLAGS`` *before* jax initialises (run ``PYTHONPATH=src python -m
+benchmarks.mesh_bench``; ``benchmarks.run --only mesh`` shells out here
+for the same reason, and runs it in-process where the devices exist).
 
 Measures, on a reduced decoder-only config over a ``("data", "model")``
 mesh with tp=8:

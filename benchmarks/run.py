@@ -39,12 +39,20 @@ def write_summary() -> str:
            f"{', '.join(sorted(merged))})"
 
 
-def _run_mesh_subprocess() -> str:
-    """mesh_bench fakes 8 host devices via XLA_FLAGS, which jax only reads
-    at init — so it must own a fresh process."""
+def _run_mesh() -> str:
+    """mesh_bench needs several devices.  Where this process already sees
+    them (a multi-chip host) it runs here: this process holds the chips,
+    so a child could not reach them.  On a one-device host it runs in a
+    CPU-only child that fakes 8 host devices via XLA_FLAGS, which jax only
+    reads at init."""
     import os
     import subprocess
-    env = dict(os.environ,
+
+    import jax
+    if len(jax.devices()) >= 2:
+        from . import mesh_bench
+        return mesh_bench.run(quick=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.mesh_bench", "--quick"],
@@ -99,7 +107,7 @@ def main():
         from . import obs_bench
         runners["obs"] = obs_bench.run
     if "mesh" in want:
-        runners["mesh"] = _run_mesh_subprocess
+        runners["mesh"] = _run_mesh
     if "resilience" in want:
         from . import resilience_bench
         runners["resilience"] = resilience_bench.run

@@ -130,7 +130,10 @@ class DelayPolynomial:
         e = self.exponents
         terms = (pows[..., e[:, 0], 0] * pows[..., e[:, 1], 1]
                  * pows[..., e[:, 2], 2])
-        return terms @ self.coeffs
+        # full f32 on every backend: a TPU's default matmul precision
+        # would move the delay that decides every AVS boost
+        return jnp.dot(terms, self.coeffs,
+                       precision=jax.lax.Precision.HIGHEST)
 
     def to_dict(self) -> Dict[str, Any]:
         return {

@@ -50,11 +50,23 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ModelConfig
 
 MODEL_AXIS = "model"
+
+
+def make_mesh(shape, axis_names, devices=None) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes.
+
+    Every layout here relies on GSPMD propagation plus
+    ``with_sharding_constraint``; ``jax.make_mesh``'s default ``Explicit``
+    axes would instead demand an output sharding at every ambiguous gather.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -20,13 +20,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _bitflip_kernel(x_ref, u_ref, pos_ref, q_ref, out_ref):
     x = x_ref[...]
     u = u_ref[...]
     pos = pos_ref[...]
-    q = q_ref[0]
+    q = q_ref[0, 0]
     mask = (jnp.int32(1) << pos.astype(jnp.int32))
     flip = u < q
     out_ref[...] = jnp.where(flip, jnp.bitwise_xor(x, mask), x)
@@ -50,8 +51,11 @@ def bitflip_words(x: jax.Array, u: jax.Array, pos: jax.Array,
         _bitflip_kernel,
         grid=grid,
         in_specs=[bspec, bspec, bspec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  # (1, 1) SMEM block: survives vmap like the fused
+                  # kernel's scalars (see fused_aged_matmul.py)
+                  pl.BlockSpec((1, 1), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM)],
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.int32),
         interpret=interpret,
-    )(x, u, pos, q)
+    )(x, u, pos, q.reshape(1, 1))

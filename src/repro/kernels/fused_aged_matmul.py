@@ -45,8 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .systolic_matmul import _CompilerParams
-
 _U = jnp.uint32
 
 
@@ -114,7 +112,10 @@ def upset_words(acc: jax.Array, bits: jax.Array, q: jax.Array) -> jax.Array:
     the uniform lands below the word-upset probability ``q``.
     """
     pos = (bits & _U(31)).astype(jnp.int32)
-    u = (bits >> _U(5)).astype(jnp.float32) * jnp.float32(2.0 ** -27)
+    # via int32: Mosaic has no uint32 -> float32 cast, and the shifted
+    # value is below 2**27, so the detour is exact
+    u = (bits >> _U(5)).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(2.0 ** -27)
     mask = jnp.left_shift(jnp.int32(1), pos)
     return jnp.where(u < q, jnp.bitwise_xor(acc, mask), acc)
 
@@ -144,10 +145,10 @@ def _fused_kernel(seed_ref, q_ref, a_ref, b_ref, *refs, k_steps: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 tiles straight into the MXU; Mosaic has no int32 matmul
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     # computed outside pl.when: interpret mode cannot lower program_id
     # inside the cond branch
@@ -155,7 +156,7 @@ def _fused_kernel(seed_ref, q_ref, a_ref, b_ref, *refs, k_steps: int,
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
-        acc = _inject(acc_ref[...], seed_ref[0], q_ref[0], tile_id,
+        acc = _inject(acc_ref[...], seed_ref[0, 0], q_ref[0, 0], tile_id,
                       hw_prng=hw_prng)
         if dequant:
             out_ref[...] = acc.astype(jnp.float32) * xs_ref[...] \
@@ -191,13 +192,16 @@ def fused_aged_matmul(a: jax.Array, b: jax.Array, xs: jax.Array | None,
     grid = (M // bm, N // bn, k_steps)
 
     q = 1.0 - (1.0 - jnp.asarray(ber, jnp.float32)) ** 32
-    seed = jnp.asarray(seed, jnp.int32).reshape(1)
-    # scalars live in SMEM: Mosaic cannot load from ANY-space refs
-    scalar_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    # scalars live in SMEM as (1, 1) blocks: under vmap (fleet lanes) each
+    # becomes a (lanes, 1, 1) array blocked (squeezed, 1, 1), whose trailing
+    # dims equal the array's, which Mosaic accepts
+    scalar_spec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0),
+                               memory_space=pltpu.SMEM)
     in_specs = [scalar_spec, scalar_spec,
                 pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
                 pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))]
-    operands = [seed, q[None], a, b]
+    operands = [jnp.asarray(seed, jnp.int32).reshape(1, 1), q.reshape(1, 1),
+                a, b]
     if dequant:
         assert xs.shape == (M, 1) and ws.shape == (1, N), (xs.shape, ws.shape)
         in_specs += [pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
@@ -213,7 +217,7 @@ def fused_aged_matmul(a: jax.Array, b: jax.Array, xs: jax.Array | None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

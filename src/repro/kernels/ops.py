@@ -258,7 +258,6 @@ def _fused_aged_matmul_sharded(xq, wq, bers, seed, mesh,
     construction; the byte win that matters (no materialised randoms, no
     separate flip-pass round-trip) is unaffected.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(xq, wq_blk, bers, seed):
@@ -268,9 +267,9 @@ def _fused_aged_matmul_sharded(xq, wq, bers, seed, mesh,
                                  interpret=interpret)
 
     col = P(None, shard_axis)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), col, P(), P()),
-                     out_specs=col, check_rep=False)(
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), col, P(), P()),
+                         out_specs=col, check_vma=False)(
         xq, wq, jnp.asarray(bers, jnp.float32),
         jnp.asarray(seed, jnp.int32))
 

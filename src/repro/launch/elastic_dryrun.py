@@ -32,6 +32,7 @@ import jax
 
 from repro.configs import get_config
 from repro.configs.shapes import SHAPES, ShapeCell
+from repro.distributed.sharding import make_mesh
 from repro.launch import dryrun as dr
 from repro.sched.disruption import run_retirement
 
@@ -48,6 +49,8 @@ def main():
                     help="fresh lanes taking the retired rack slots")
     ap.add_argument("--quick", action="store_true",
                     help="reduced arch + tiny mesh/cell for CI smoke")
+    ap.add_argument("--out-dir", default=RESULTS,
+                    help="directory the JSON report is written to")
     args = ap.parse_args()
 
     if args.quick:
@@ -72,7 +75,7 @@ def main():
           f"survivors resumed at {s['survivor_pre_max_dvp_mv']:.1f}mV")
 
     # Serving side: the SAME train step compiles on the degraded mesh.
-    mesh = jax.make_mesh(plan.new_shape, plan.axis_names)
+    mesh = make_mesh(plan.new_shape, plan.axis_names)
     cfg = get_config(args.arch)
     if args.quick:
         cfg = cfg.reduced()
@@ -95,9 +98,9 @@ def main():
     if mem is not None:
         report["temp_size_in_bytes"] = int(
             getattr(mem, "temp_size_in_bytes", 0))
-    os.makedirs(RESULTS, exist_ok=True)
+    os.makedirs(args.out_dir, exist_ok=True)
     out_path = os.path.join(
-        RESULTS, f"elastic__{args.arch}__{cell.name}__{new_chips}chips"
+        args.out_dir, f"elastic__{args.arch}__{cell.name}__{new_chips}chips"
                  f"{'__quick' if args.quick else ''}.json")
     with open(out_path, "w") as f:
         json.dump(report, f, indent=1)

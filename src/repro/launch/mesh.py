@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.distributed.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod.
@@ -15,23 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Whatever devices exist, data-major (CPU tests / small runs)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
-
-
-def make_serve_mesh(tp: int | None = None):
-    """Tensor-parallel serving mesh: ``("data", "model")`` with model=tp.
-
-    Default tp: every visible device (the single-replica big-model case
-    ``repro.serve.sharded.MeshServeEngine`` targets).
-    """
-    n = len(jax.devices())
-    tp = n if tp is None else int(tp)
-    assert n % tp == 0, (n, tp)
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -17,16 +17,15 @@ from __future__ import annotations
 
 import argparse
 
-import jax
 import numpy as np
 
-from repro.configs import get_config
 from repro.core.fleet import FleetRuntime
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import serve_model
 from repro.sched.router import ROUTER_REGISTRY
 from repro.sched.workload import WORKLOADS, get_workload
 from repro.serve.online import (OnlineFleetEngine, OnlineServeEngine,
                                 requests_from_workload)
-from repro.train.steps import init_train_state
 
 YEAR_S = 365.25 * 24 * 3600.0
 
@@ -76,8 +75,8 @@ def main(argv=None):
         args.prompt_len = min(args.prompt_len, 8)
         args.chunk_steps = min(args.chunk_steps, 4)
 
-    cfg = get_config(args.arch).reduced()
-    params = init_train_state(cfg, jax.random.PRNGKey(0)).params
+    enable_compile_cache()
+    cfg, params = serve_model(args.arch)
     fleet = FleetRuntime(n_devices=args.n_devices)
     for i in range(args.n_devices):
         fleet.set_age(years=args.age_years * (i + 1) / args.n_devices,

@@ -1,9 +1,11 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the aging-aware engine end-to-end on a reduced config: initialises
-params, builds a :class:`repro.core.fleet.FleetRuntime` (``--n-devices``
-simulated accelerators of possibly different age), and generates batched
-tokens under the per-operator BERs the policy admits at each device's age.
+Runs the aging-aware engine end-to-end on a reduced config (``--full``:
+the published widths in bfloat16, depth cut with ``--n-layers``):
+initialises serving params (no optimizer state), builds a
+:class:`repro.core.fleet.FleetRuntime` (``--n-devices`` simulated
+accelerators of possibly different age), and generates batched tokens
+under the per-operator BERs the policy admits at each device's age.
 
 With ``--n-devices > 1`` the whole fleet serves in ONE dispatch: the
 prompt batch is sharded across lanes and
@@ -32,19 +34,39 @@ dispatch (:class:`repro.serve.sharded.MeshServeEngine`).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.fleet import FleetRuntime
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sched.router import ROUTER_REGISTRY
 from repro.sched.workload import WORKLOADS
 from repro.serve.engine import FleetServeEngine, ServeEngine
-from repro.train.steps import init_train_state
 
 YEAR_S = 365.25 * 24 * 3600.0
+
+
+def serve_model(arch: str, *, full: bool = False, n_layers=None,
+                seed: int = 0):
+    """(cfg, params) for serving: the reduced CPU config in float32, or
+    with ``full`` the published widths in bfloat16; ``n_layers`` cuts the
+    depth.  Parameters only — serving needs no optimizer state."""
+    from repro.models import encdec
+    from repro.models import transformer as tf
+    cfg = get_config(arch)
+    if not full:
+        cfg = cfg.reduced()
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=int(n_layers))
+    init = encdec.init_params if cfg.n_encoder_layers else tf.init_params
+    dtype = jnp.bfloat16 if full else jnp.float32
+    return cfg, jax.jit(init, static_argnums=(0, 2))(
+        cfg, jax.random.PRNGKey(seed), dtype)
 
 
 def _print_cache_stats():
@@ -71,6 +93,11 @@ def main(argv=None):
                          "batching instead of a static prompt batch "
                          "(remaining args go to repro.launch.online)")
     ap.add_argument("--arch", default="deepseek_7b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published widths (bfloat16) instead "
+                         "of the reduced CPU config")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the model to this many layers")
     ap.add_argument("--age-years", type=float, default=5.0)
     ap.add_argument("--n-devices", type=int, default=1,
                     help="fleet size; device i serves at age-years * "
@@ -137,8 +164,9 @@ def main(argv=None):
                          "stats after the run")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch).reduced()
-    params = init_train_state(cfg, jax.random.PRNGKey(0)).params
+    enable_compile_cache()
+    cfg, params = serve_model(args.arch, full=args.full,
+                              n_layers=args.n_layers)
     pol = args.policy or ("baseline" if args.baseline_avs
                           else "fault_tolerant")
     if pol == "measured":

@@ -17,6 +17,7 @@ from repro.configs import get_config
 from repro.data import SyntheticLM
 from repro.distributed.sharding import (batch_spec, input_shardings,
                                         state_specs)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim import AdamWConfig
 from repro.train.loop import LoopConfig, TrainLoop
@@ -38,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -65,7 +65,7 @@ def default_serve_mesh(tp: Optional[int] = None) -> Mesh:
     n = len(jax.devices())
     tp = n if tp is None else int(tp)
     assert n % tp == 0, (n, tp)
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return shrules.make_mesh((n // tp, tp), ("data", "model"))
 
 
 @compile_cache("mesh_generate")

@@ -19,7 +19,6 @@ from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ModelConfig
@@ -165,11 +164,11 @@ def make_dp_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh: Mesh, *,
                           opt=OptState(replicated, replicated, replicated),
                           residuals=res_spec)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_sp, P(axes)),
         out_specs=(state_sp, replicated),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(mapped, donate_argnums=(0,))
 
 

@@ -28,6 +28,7 @@ from repro.sched.disruption import (recovered_totals, run_flash_crowd,
 from repro.sched.workload import WORKLOADS
 
 YEAR_S = 365.25 * 24 * 3600.0
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -230,9 +231,10 @@ def test_schedule_cli_scenarios_inprocess(capsys):
 def test_elastic_dryrun_quick_subprocess(tmp_path):
     env = dict(os.environ, PYTHONPATH="src")
     proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.elastic_dryrun", "--quick"],
-        capture_output=True, text=True, timeout=600, cwd="/root/repo",
-        env=env)
+        [sys.executable, "-m", "repro.launch.elastic_dryrun", "--quick",
+         "--out-dir", str(tmp_path)],
+        capture_output=True, text=True, timeout=600, cwd=REPO, env=env)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "degraded-mesh train step compiles" in proc.stdout
+    assert list(tmp_path.glob("elastic__*.json"))
     assert "survivors resumed" in proc.stdout

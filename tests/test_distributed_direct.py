@@ -10,6 +10,7 @@ and a value-preserving reshard across a device-count change on a 3-axis
 subprocess.
 """
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -18,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.collectives import (psum_compressed_leaf,
@@ -26,6 +27,8 @@ from repro.distributed.collectives import (psum_compressed_leaf,
                                            tree_psum, tree_psum_compressed,
                                            zeros_residuals)
 from repro.distributed.elastic import plan_remesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------------------------------- #
@@ -167,18 +170,19 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.distributed.collectives import (psum_compressed_leaf,
                                                tree_psum)
     from repro.distributed.elastic import (make_mesh_from_plan, plan_remesh,
                                            reshard_state)
+    from repro.distributed.sharding import make_mesh
     from repro.models import transformer as tf
 
     out = {}
     # --- compressed psum across 8 real shards vs the plain mean ---------
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32))
 
     def body(gs, rs):
@@ -214,7 +218,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     # --- reshard across a device-count change on a 3-axis mesh ----------
     cfg = get_config("deepseek_7b").reduced()
     params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     from repro.distributed.sharding import param_specs
     specs = param_specs(jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params),
@@ -249,7 +253,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
 def test_multidevice_collectives_and_reshard():
     proc = subprocess.run([sys.executable, "-c", MULTIDEV_SCRIPT],
                           capture_output=True, text=True, timeout=900,
-                          cwd="/root/repo")
+                          cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines()
             if l.startswith("RESULT ")][0]

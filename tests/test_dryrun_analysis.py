@@ -1,5 +1,7 @@
 """Roofline extraction utilities + the scan-trip-blindness evidence that
 motivates the probe methodology (launch/dryrun.py docstring)."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,8 @@ import pytest
 from repro.configs import get_config
 from repro.configs.shapes import SHAPES, applicable
 from repro.launch import analysis
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------------------------------- #
@@ -48,8 +52,9 @@ def test_collective_parser_on_real_lowering():
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, json
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.distributed.sharding import make_mesh
         from repro.launch import analysis
-        mesh = jax.make_mesh((4,), ("k",))
+        mesh = make_mesh((4,), ("k",))
         f = jax.jit(lambda a, b: a @ b,
                     in_shardings=(NamedSharding(mesh, P(None, "k")),
                                   NamedSharding(mesh, P("k", None))),
@@ -60,7 +65,7 @@ def test_collective_parser_on_real_lowering():
     """)
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=300,
-                          cwd="/root/repo")
+                          cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][0]
     got = json.loads(line[len("RESULT "):])

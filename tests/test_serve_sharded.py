@@ -22,6 +22,8 @@ from repro.kernels import ops as kops
 from repro.models.layers import FaultConfig, op_batched_matmul, op_linear
 from repro.serve.engine import FleetServeEngine
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 # --------------------------------------------------------------------------- #
 # shard_slices / inject_bitflips_sharded unit semantics (single device)
@@ -200,7 +202,7 @@ def test_fleet_engine_rejects_shard_granular_fleet():
 def _run_script(script: str, timeout: int) -> dict:
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=timeout,
-                          cwd="/root/repo")
+                          cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines()
             if l.startswith("RESULT ")][0]

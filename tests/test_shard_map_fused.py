@@ -14,6 +14,7 @@ mesh exercises the real shard_map machinery); the tp in {2, 4, 8} x
 devices in a subprocess, like the rest of the multi-device coverage.
 """
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -24,8 +25,11 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.distributed.sharding import make_mesh
 from repro.kernels import ops as kops
 from repro.kernels import ref
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------------------------------- #
@@ -78,7 +82,7 @@ def test_shard_map_route_single_device_parity():
     """A tp=1 mesh runs the real shard_map + Pallas route in-process: the
     lowering must contain the pallas_call and the jitted output must be
     bit-exact vs the jitted kernel-free oracle (clean and faulted)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 64), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 96), jnp.float32)
     seed = jnp.int32(9)
@@ -104,7 +108,7 @@ def test_aged_linear_downgrades_without_mesh():
     because the streams match."""
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 32), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(3), (32, 64), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     seed = jnp.int32(3)
     cases = [
         (jnp.float32([0.05]), {}),                      # fused flags, no mesh
@@ -124,7 +128,7 @@ def test_aged_linear_downgrades_without_mesh():
 def test_serve_shard_map_info_gating():
     from repro.distributed import sharding as shrules
     assert shrules.serve_shard_map_info(64) is None       # no scope
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with shrules.serve_mesh_scope(mesh):
         assert shrules.serve_shard_map_info(64) is None   # tp == 1
 
@@ -166,27 +170,29 @@ PARITY_SCRIPT = textwrap.dedent("""
     for arch, tps in GRID.items():
         cfg = get_config(arch).reduced()
         params = init_train_state(cfg, jax.random.PRNGKey(0)).params
-        prompts = (np.arange(2 * 4).reshape(2, 4) * 31 % cfg.vocab
+        # batch 4 x 6 steps: enough upsets at ~1e-5 BER that every combo's
+        # faults reach a sampled token
+        prompts = (np.arange(4 * 4).reshape(4, 4) * 31 % cfg.vocab
                    ).astype(np.int32)
         rng = np.random.RandomState(0)
         extras = {}
         if cfg.prefix_tokens:
             extras["prefix_embeds"] = rng.randn(
-                2, cfg.prefix_tokens, cfg.d_model).astype(np.float32)
+                4, cfg.prefix_tokens, cfg.d_model).astype(np.float32)
         if cfg.n_encoder_layers:
             extras["frames"] = rng.randn(
-                2, cfg.encoder_seq, cfg.d_model).astype(np.float32)
+                4, cfg.encoder_seq, cfg.d_model).astype(np.float32)
         for tp in tps:
             fl = FleetRuntime(n_devices=1, n_shards=tp)
             engs = {
                 route: MeshServeEngine(cfg, params, fleet=fl, tp=tp,
-                                       max_len=16, seed=3,
+                                       max_len=24, seed=3,
                                        use_fused_kernel=(route == "fused"))
                 for route in ("fused", "free")}
             combo = {}
             steps.TRACE_COUNTS.clear()
             mark(f"[parity] {arch} tp={tp} compiling clean (age 0)")
-            clean = {r: e.generate(prompts, 3, **extras)
+            clean = {r: e.generate(prompts, 6, **extras)
                      for r, e in engs.items()}
             combo["clean_exact"] = bool(np.array_equal(
                 clean["fused"].tokens, clean["free"].tokens))
@@ -194,7 +200,7 @@ PARITY_SCRIPT = textwrap.dedent("""
             for s in range(tp):              # heterogeneous shard ages
                 fl.set_age(years=2.0 + 7.0 * s / max(tp - 1, 1), shard=s)
             mark(f"[parity] {arch} tp={tp} faulted pass")
-            faulted = {r: e.generate(prompts, 3, **extras)
+            faulted = {r: e.generate(prompts, 6, **extras)
                        for r, e in engs.items()}
             combo["faulted_exact"] = bool(np.array_equal(
                 faulted["fused"].tokens, faulted["free"].tokens))
@@ -216,7 +222,7 @@ def test_shard_map_fused_generation_parity_grid():
     age/BER update between the two passes."""
     proc = subprocess.run([sys.executable, "-c", PARITY_SCRIPT],
                           capture_output=True, text=True, timeout=1500,
-                          cwd="/root/repo")
+                          cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines()
             if l.startswith("RESULT ")][0]

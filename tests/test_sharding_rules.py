@@ -7,6 +7,7 @@ installed, the deterministic fallback shim otherwise); the explicit tests
 below them pin each documented serve-layout fallback to the config that
 fires it."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -21,6 +22,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed.sharding import batch_spec, param_specs
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeMesh:
@@ -302,7 +305,8 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.data import SyntheticLM
-    from repro.distributed.sharding import param_specs, state_specs
+    from repro.distributed.sharding import (make_mesh, param_specs,
+                                            state_specs)
     from repro.distributed.elastic import (make_mesh_from_plan, plan_remesh,
                                            reshard_state)
     from repro.optim import AdamWConfig
@@ -312,7 +316,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
 
     out = {}
     cfg = get_config("deepseek_7b").reduced()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     data = SyntheticLM(vocab=cfg.vocab, seq_len=32, global_batch=8)
 
     # --- pjit TP+DP step executes and matches single-device math ----------
@@ -334,7 +338,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     out["pjit_loss_delta"] = abs(float(m1["loss"]) - float(m2["loss"]))
 
     # --- compressed-DP shard_map step approximates exact DP ---------------
-    mesh_dp = jax.make_mesh((8,), ("data",))
+    mesh_dp = make_mesh((8,), ("data",))
     st = init_train_state(cfg, jax.random.PRNGKey(0))
     res = dp_residuals_init(st.params, mesh_dp)
     st_c = TrainState(st.params, st.opt, res)
@@ -372,7 +376,7 @@ def test_multidevice_integration():
     8 faked devices in a subprocess."""
     proc = subprocess.run([sys.executable, "-c", MULTIDEV_SCRIPT],
                           capture_output=True, text=True, timeout=900,
-                          cwd="/root/repo")
+                          cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][0]
     out = json.loads(line[len("RESULT "):])
