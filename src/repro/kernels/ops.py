@@ -325,10 +325,13 @@ def fold_seed(seed: jax.Array, *indices) -> jax.Array:
 
 
 def quantize_int8(x: jax.Array, axis: int = -1):
-    """Symmetric per-row absmax int8 quantisation; returns (q, scale)."""
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+    """Symmetric per-row absmax int8 quantisation; returns (q, scale).
+
+    Its ops carry ``jax.named_scope("quantize")`` in their metadata."""
+    with jax.named_scope("quantize"):
+        amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
+        scale = jnp.maximum(amax, 1e-8) / 127.0
+        q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
 

@@ -144,7 +144,11 @@ def dequant_tree(tree, dtype=jnp.bfloat16):
 # --------------------------------------------------------------------------- #
 def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                 cache=None, cache_len=None, fi=None, salt=0):
-    """Self-attention + FFN block.  With ``cache`` (decode): single token."""
+    """Self-attention + FFN block.  With ``cache`` (decode): single token.
+
+    Each attention call runs under ``jax.named_scope("attention")``, which
+    names its ops (scores, softmax, SV, their injection) in the metadata
+    a profiler trace's reduction reads."""
     h = norm(x, bp["norm1"], cfg.norm)
     ap = bp["attn"]
     q = op_einsum("bsd,dhk->bshk", h, ap["wq"], "q", fi, salt)
@@ -184,12 +188,16 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                 kc = write(cache["k"], k)
                 vc = write(cache["v"], v)
                 k_at, v_at = kc, vc
-            out = attn_lib.decode_attention(q, k_at, v_at, cache_len, fi=fi,
-                                            salt=salt)
+            with jax.named_scope("attention"):
+                out = attn_lib.decode_attention(q, k_at, v_at, cache_len,
+                                                fi=fi, salt=salt)
             new_cache = {"k": kc, "v": vc}
         else:                    # prefill: run full attn, stash K/V
-            out = attn_lib.attention(q, k, v, causal=True, window=cfg.window,
-                                     prefix_len=prefix_len, fi=fi, salt=salt)
+            with jax.named_scope("attention"):
+                out = attn_lib.attention(q, k, v, causal=True,
+                                         window=cfg.window,
+                                         prefix_len=prefix_len, fi=fi,
+                                         salt=salt)
             S = k.shape[1]
             if S >= kv_len:      # windowed: keep the last kv_len tokens,
                                  # rolled so token t sits at slot t % kv_len
@@ -203,8 +211,9 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                 kc, vc = quantize_cache_entry(kc), quantize_cache_entry(vc)
             new_cache = {"k": kc, "v": vc}
     else:
-        out = attn_lib.attention(q, k, v, causal=True, window=cfg.window,
-                                 prefix_len=prefix_len, fi=fi, salt=salt)
+        with jax.named_scope("attention"):
+            out = attn_lib.attention(q, k, v, causal=True, window=cfg.window,
+                                     prefix_len=prefix_len, fi=fi, salt=salt)
     x = x + op_einsum("bshk,hkd->bsd", out, ap["wo"], "o", fi, salt)
 
     h2 = norm(x, bp["norm2"], cfg.norm)

@@ -89,6 +89,24 @@ class Gauge:
             yield Sample(self.name, (), self.value, "gauge", self.help)
 
 
+def _add_exact(partials: List[float], x: float) -> None:
+    """Add ``x`` to ``partials`` without rounding: afterwards the exact
+    sum of the list has grown by exactly ``x``, and the list holds
+    non-overlapping floats in increasing magnitude (Shewchuk's
+    algorithm, as ``math.fsum`` keeps it)."""
+    i = 0
+    for y in partials:
+        if abs(x) < abs(y):
+            x, y = y, x
+        hi = x + y
+        lo = y - (hi - x)
+        if lo:
+            partials[i] = lo
+            i += 1
+        x = hi
+    partials[i:] = [x]
+
+
 class StreamingHistogram:
     """Log-bucketed streaming histogram with relative-error-bounded
     quantiles and exactly-associative merge.
@@ -101,11 +119,14 @@ class StreamingHistogram:
     Non-positive observations (latency/telemetry metrics are naturally
     ``>= 0``; zeros happen) collapse into one underflow bucket whose
     quantile estimate is the exact running ``min``.  ``count/sum/min/max``
-    are exact.
+    are exact: ``sum`` is kept as non-overlapping float partials (the
+    ``math.fsum`` scheme) and read as their correctly rounded total, so it
+    does not depend on the order in which values were added.
 
-    ``merge`` adds per-bucket counts — associative and commutative by
-    construction, so partial histograms from different shards/processes
-    fold in any order to the identical state.
+    ``merge`` adds per-bucket counts and folds the partials together —
+    associative and commutative by construction, so partial histograms
+    from different shards/processes fold in any order to the identical
+    state.
     """
 
     kind = "histogram"
@@ -119,7 +140,7 @@ class StreamingHistogram:
         self.buckets: Dict[int, int] = {}
         self.n_nonpos = 0
         self.count = 0
-        self.sum = 0.0
+        self._partials: List[float] = []
         self.min = math.inf
         self.max = -math.inf
 
@@ -127,7 +148,7 @@ class StreamingHistogram:
     def observe(self, value: float) -> None:
         v = float(value)
         self.count += 1
-        self.sum += v
+        _add_exact(self._partials, v)
         self.min = min(self.min, v)
         self.max = max(self.max, v)
         if v <= 0.0:
@@ -167,6 +188,10 @@ class StreamingHistogram:
         return self.quantile(0.99)
 
     @property
+    def sum(self) -> float:
+        return math.fsum(self._partials)
+
+    @property
     def mean(self) -> float:
         return self.sum / self.count if self.count else math.nan
 
@@ -181,7 +206,9 @@ class StreamingHistogram:
             out.buckets[b] = out.buckets.get(b, 0) + c
         out.n_nonpos = self.n_nonpos + other.n_nonpos
         out.count = self.count + other.count
-        out.sum = self.sum + other.sum
+        out._partials = list(self._partials)
+        for p in other._partials:
+            _add_exact(out._partials, p)
         out.min = min(self.min, other.min)
         out.max = max(self.max, other.max)
         return out

@@ -108,17 +108,19 @@ def logit_taps(logits: jnp.ndarray,
     crater it) and the batch-mean top1−top2 margin (sampling confidence).
     ``active`` (online serving) masks out idle slots whose logits are
     garbage; with no live slot the masked means are 0 by convention.
+    Its ops carry ``jax.named_scope("taps")`` in their metadata.
     """
-    top2 = jax.lax.top_k(logits, 2)[0]              # (batch, 2)
-    peak = top2[:, 0]
-    margin = top2[:, 0] - top2[:, 1]
-    if active is not None:
-        w = active.astype(logits.dtype)
-        denom = jnp.maximum(jnp.sum(w), 1.0)
-        return {"logit_max": jnp.sum(peak * w) / denom,
-                "logit_margin": jnp.sum(margin * w) / denom}
-    return {"logit_max": jnp.mean(peak),
-            "logit_margin": jnp.mean(margin)}
+    with jax.named_scope("taps"):
+        top2 = jax.lax.top_k(logits, 2)[0]          # (batch, 2)
+        peak = top2[:, 0]
+        margin = top2[:, 0] - top2[:, 1]
+        if active is not None:
+            w = active.astype(logits.dtype)
+            denom = jnp.maximum(jnp.sum(w), 1.0)
+            return {"logit_max": jnp.sum(peak * w) / denom,
+                    "logit_margin": jnp.sum(margin * w) / denom}
+        return {"logit_max": jnp.mean(peak),
+                "logit_margin": jnp.mean(margin)}
 
 
 def cosim_taps(cos, scenario) -> "Telemetry":

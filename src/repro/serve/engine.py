@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Dict, Optional
 
 import jax
@@ -48,6 +47,7 @@ from repro.configs import ModelConfig
 from repro.core.fleet import FleetRuntime
 from repro.models.layers import FaultConfig
 from repro.obs import metrics as obs_metrics
+from repro.obs.spans import span
 from repro.obs.taps import taps_enabled, telemetry_to_host
 from . import steps
 
@@ -260,40 +260,47 @@ class ServeEngine:
         bucket).  ``scan=False`` runs the per-token eager loop — the
         oracle path, bit-exact with the default scanned path.
         """
-        cfg = self.cfg
-        fi = self._fault_config()
-        self._key, call_key = jax.random.split(self._key)
-        temp = self._temperature(greedy, temperature)
-        prompts = jnp.asarray(prompts, jnp.int32)
-        extras = self._extras(prefix_embeds, frames)
+        with span("serve.generate"):
+            with span("serve.prepare"):
+                cfg = self.cfg
+                fi = self._fault_config()
+                self._key, call_key = jax.random.split(self._key)
+                temp = self._temperature(greedy, temperature)
+                prompts = jnp.asarray(prompts, jnp.int32)
+                extras = self._extras(prefix_embeds, frames)
 
-        telemetry = None
-        if scan:
-            m0 = _generate_fn.misses
-            gen = _generate_fn(cfg, self.max_len, int(n_steps), top_k)
-            t0 = time.perf_counter()
-            tokens_dev, telem = gen(self.params, prompts, fi, call_key,
-                                    temp, *extras)
-            tokens = np.asarray(tokens_dev)
-            span = time.perf_counter() - t0
-            # host-side only: whether to transfer + record the aux leaves;
-            # the compiled dispatch above is identical either way
-            if taps_enabled():
-                telemetry = telemetry_to_host(telem)
-                self._record(tokens, telemetry, span,
-                             cold=_generate_fn.misses > m0)
-        else:
-            tokens = self._generate_eager(prompts, int(n_steps), fi,
-                                          call_key, temp, top_k, extras)
+            telem = None
+            if scan:
+                m0 = _generate_fn.misses
+                gen = _generate_fn(cfg, self.max_len, int(n_steps), top_k)
+                with span("serve.dispatch") as dispatch:
+                    tokens_dev, telem = gen(self.params, prompts, fi,
+                                            call_key, temp, *extras)
+                with span("serve.wait") as wait:
+                    tokens = np.asarray(tokens_dev)
+            else:
+                tokens = self._generate_eager(prompts, int(n_steps), fi,
+                                              call_key, temp, top_k, extras)
 
-        bers = (self.runtime.op_bers() if self.runtime else {})
-        return GenerateResult(
-            tokens=tokens,
-            bers={k: float(v) for k, v in bers.items()},
-            age_years=self.runtime.age_years if self.runtime else 0.0,
-            power_w=self.runtime.total_power() if self.runtime else 0.0,
-            telemetry=telemetry,
-        )
+            with span("serve.finish"):
+                telemetry = None
+                # host-side only: whether to transfer + record the aux
+                # leaves; the compiled dispatch above is identical either way
+                if telem is not None and taps_enabled():
+                    telemetry = telemetry_to_host(telem)
+                    self._record(tokens, telemetry,
+                                 dispatch.seconds + wait.seconds,
+                                 cold=_generate_fn.misses > m0)
+                bers = (self.runtime.op_bers() if self.runtime else {})
+                return GenerateResult(
+                    tokens=tokens,
+                    bers={k: float(v) for k, v in bers.items()},
+                    age_years=(self.runtime.age_years if self.runtime
+                               else 0.0),
+                    power_w=(self.runtime.total_power() if self.runtime
+                             else 0.0),
+                    telemetry=telemetry,
+                )
 
     def _record(self, tokens, telemetry, span_s: float, cold: bool) -> None:
         """Fold one generate call into the metrics registry (host-side)."""
@@ -485,11 +492,10 @@ class FleetServeEngine:
 
         m0 = _fleet_generate_fn.misses
         gen = _fleet_generate_fn(cfg, self.max_len, int(n_steps), top_k)
-        t0 = time.perf_counter()
-        tokens, telem = gen(self.params, prompts, fi, keys,
-                            jnp.float32(temperature), *extras)
-        tokens = np.asarray(tokens)
-        span = time.perf_counter() - t0
+        with span("fleet.generate") as call:
+            tokens, telem = gen(self.params, prompts, fi, keys,
+                                jnp.float32(temperature), *extras)
+            tokens = np.asarray(tokens)
         telemetry = None
         if taps_enabled():
             # vmapped dispatch: every tap leaf carries the lane axis (N, T)
@@ -501,7 +507,7 @@ class FleetServeEngine:
             obs_metrics.observe_span(
                 "fleet_generate_compile_s"
                 if _fleet_generate_fn.misses > m0
-                else "fleet_generate_warm_s", span)
+                else "fleet_generate_warm_s", call.seconds)
 
         snap = self.fleet.snapshot()
         return FleetGenerateResult(

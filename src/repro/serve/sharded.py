@@ -32,7 +32,6 @@ contract rests on (see the module docstring of
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Optional
 
 import jax
@@ -45,6 +44,7 @@ from repro.core.fleet import FleetRuntime
 from repro.distributed import sharding as shrules
 from repro.models.layers import FaultConfig
 from repro.obs import metrics as obs_metrics
+from repro.obs.spans import span
 from repro.obs.taps import taps_enabled, telemetry_to_host
 from . import steps
 from .engine import ServeEngine, compile_cache
@@ -228,11 +228,10 @@ class MeshServeEngine:
         m0 = _mesh_generate_fn.misses
         gen = _mesh_generate_fn(cfg, self.max_len, int(n_steps), top_k,
                                 self.mesh)
-        t0 = time.perf_counter()
-        tokens, telem = gen(self.params, prompts, fi, call_key, temp,
-                            *extras)
-        tokens = np.asarray(tokens)
-        span = time.perf_counter() - t0
+        with span("mesh.generate") as call:
+            tokens, telem = gen(self.params, prompts, fi, call_key, temp,
+                                *extras)
+            tokens = np.asarray(tokens)
         telemetry = None
         if taps_enabled():
             # taps are replicated scalars per step under the serve layout —
@@ -243,7 +242,7 @@ class MeshServeEngine:
             obs_metrics.observe_span(
                 "mesh_generate_compile_s"
                 if _mesh_generate_fn.misses > m0
-                else "mesh_generate_warm_s", span)
+                else "mesh_generate_warm_s", call.seconds)
 
         if self.fleet is not None:
             ops = self.fleet.operators

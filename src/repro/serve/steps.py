@@ -201,6 +201,11 @@ def make_generate_fn(cfg: ModelConfig, max_len: int, n_steps: int,
     Tokens generated past a ring-buffered (windowed) cache's capacity
     follow the same ring semantics as the eager loop (both call the same
     ``decode_step``).
+
+    The prompt pass runs under ``jax.named_scope("prefill")`` and each
+    decode step under ``"decode"``: the scopes live only in the ops'
+    metadata (``op_name``), where a profiler trace's reduction finds
+    them.
     """
     prefill = make_prefill_fn(cfg, max_len)
     decode = make_decode_fn(cfg)
@@ -209,18 +214,20 @@ def make_generate_fn(cfg: ModelConfig, max_len: int, n_steps: int,
     def generate(params, prompts, fi, key, temperature, *extras):
         TRACE_COUNTS["generate"] += 1
         S = prompts.shape[1]
-        if fi is not None:
-            # hoist the per-op threefry stream bases out of the scan body:
-            # in-loop derivation is then pure fmix32 integer folds
-            fi = fi.with_seeds()
-        out = prefill(params, prompts, fi, *extras)
-        logits, cache = out[0], out[1]
-        kv = out[2] if has_kv else None
-        key, sub = jax.random.split(key)
-        tok = sample_token(logits, sub, temperature, top_k)
-        tap0 = logit_taps(logits)
+        with jax.named_scope("prefill"):
+            if fi is not None:
+                # hoist the per-op threefry stream bases out of the scan
+                # body: in-loop derivation is then pure fmix32 integer folds
+                fi = fi.with_seeds()
+            out = prefill(params, prompts, fi, *extras)
+            logits, cache = out[0], out[1]
+            kv = out[2] if has_kv else None
+            key, sub = jax.random.split(key)
+            tok = sample_token(logits, sub, temperature, top_k)
+            tap0 = logit_taps(logits)
         cache_len0 = S + cfg.prefix_tokens
 
+        @jax.named_scope("decode")
         def body(carry, t):
             tok, cache, key = carry
             cache_len = jnp.asarray(cache_len0 + t, jnp.int32)
