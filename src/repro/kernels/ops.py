@@ -11,19 +11,28 @@
   upsets drawn by the in-kernel PRNG at the accumulator flush, dequant
   fused, nothing but ``a``, ``b``, scales and the float output touching
   HBM.  The seed-free three-pass route survives as the oracle fallback.
+  It takes the weight as a float array, quantised in place, or as a
+  :class:`QuantizedWeight` that the caller quantised ahead.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.metrics import REGISTRY
 from . import ref
 from .bitflip import bitflip_words
 from .fused_aged_matmul import fused_aged_matmul as _fused_aged_matmul_kernel
 from .systolic_matmul import systolic_matmul
+
+# faulted weight-matmul sites by where their weight was quantised:
+# "prequantized" (a QuantizedWeight) or "inline" (quantised in the call).
+# Ticks when the Python body runs, i.e. while jax traces a jitted caller.
+AGED_WEIGHTS = REGISTRY.trace_counter("aged_weights")
 
 
 def _default_interpret() -> bool:
@@ -335,7 +344,33 @@ def quantize_int8(x: jax.Array, axis: int = -1):
     return q, scale
 
 
-def aged_linear(x: jax.Array, w: jax.Array, *, ber=0.0,
+def quantize_weight(w: jax.Array):
+    """Per-column int8 quantisation of a ``(..., K, N)`` weight, the
+    scale in float32 whatever ``w``'s dtype.  A bf16 scale would be
+    rounded where it is stored and not where XLA fuses its computation
+    into the consumer, so a weight quantised in place and one quantised
+    ahead would dequantise differently; in float32 nothing is rounded."""
+    return quantize_int8(w.astype(jnp.float32), axis=-2)
+
+
+@dataclasses.dataclass(frozen=True)
+class QuantizedWeight:
+    """A float weight viewed as ``(K, N)`` and quantised ahead for
+    :func:`aged_linear`: ``q`` int8 ``(K, N)`` and ``scale`` ``(1, N)``,
+    exactly ``quantize_weight(w)``.  ``out_dims`` are the float
+    weight's output dims (``(N,)``, or ``(H, hd)`` for a fused-head
+    projection).  A registered pytree: stacked layers carry a leading axis
+    on ``q`` and ``scale``, which a layer scan slices away."""
+    q: jax.Array
+    scale: jax.Array
+    out_dims: tuple
+
+
+jax.tree_util.register_dataclass(
+    QuantizedWeight, data_fields=("q", "scale"), meta_fields=("out_dims",))
+
+
+def aged_linear(x: jax.Array, w, *, ber=0.0,
                 key: jax.Array | None = None,
                 seed: jax.Array | None = None,
                 interpret: bool | None = None,
@@ -377,6 +412,13 @@ def aged_linear(x: jax.Array, w: jax.Array, *, ber=0.0,
       never sampled tokens, and the kernel-free route doubles as the
       shard_map route's oracle (``tests/test_shard_map_fused.py``).
     """
+    if isinstance(w, QuantizedWeight):
+        AGED_WEIGHTS["prequantized"] += 1
+        wq, ws = w.q, w.scale
+    else:
+        AGED_WEIGHTS["inline"] += 1
+        wq, ws = quantize_weight(w)
+    N = wq.shape[1]
     sharded = jnp.ndim(ber) == 1
     inject = key is not None or seed is not None
     shard_mapped = False
@@ -386,7 +428,7 @@ def aged_linear(x: jax.Array, w: jax.Array, *, ber=0.0,
                         and shard_axis is not None
                         and shard_axis in mesh.axis_names
                         and int(mesh.shape[shard_axis]) == S
-                        and w.shape[1] % S == 0)
+                        and N % S == 0)
         if not shard_mapped:
             # documented downgrade: same streams, kernel-free executor
             use_kernel = fused = False
@@ -394,7 +436,6 @@ def aged_linear(x: jax.Array, w: jax.Array, *, ber=0.0,
     K = x.shape[-1]
     x2 = x.reshape(-1, K)
     xq, xs = quantize_int8(x2, axis=-1)
-    wq, ws = quantize_int8(w, axis=0)
     if sharded and inject:
         if seed is None:
             seed = seed_from_key(key)
@@ -408,13 +449,13 @@ def aged_linear(x: jax.Array, w: jax.Array, *, ber=0.0,
         # => identical XLA rewrites => cross-route bit-exactness survives
         # the simplifier's broadcast-multiply reassociation freedom
         out = acc.astype(jnp.float32) * xs * ws
-        return out.reshape(*lead, w.shape[1]).astype(x.dtype)
+        return out.reshape(*lead, N).astype(x.dtype)
     if use_kernel and fused and inject:
         if seed is None:
             seed = seed_from_key(key)
         out = fused_aged_matmul(xq, wq, xs, ws, ber=ber, seed=seed,
                                 interpret=interpret)
-        return out.reshape(*lead, w.shape[1]).astype(x.dtype)
+        return out.reshape(*lead, N).astype(x.dtype)
     if use_kernel:
         acc = quantized_matmul(xq, wq, interpret=interpret)
     else:
@@ -427,4 +468,4 @@ def aged_linear(x: jax.Array, w: jax.Array, *, ber=0.0,
         acc = (inject_bitflips(acc, ber, key, interpret=interpret)
                if use_kernel else inject_bitflips_ref(acc, ber, key))
     out = acc.astype(jnp.float32) * xs * ws
-    return out.reshape(*lead, w.shape[1]).astype(x.dtype)
+    return out.reshape(*lead, N).astype(x.dtype)

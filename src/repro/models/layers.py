@@ -11,6 +11,7 @@ dense op, keeping the lowered HLO free of simulation artefacts.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Optional
 
 import jax
@@ -96,9 +97,13 @@ def _op_salt(op: str) -> int:
     return _OP_IDS.get(op, 31)
 
 
-def op_linear(x: jax.Array, w: jax.Array, op: str,
+def op_linear(x: jax.Array, w, op: str,
               fi: Optional[FaultConfig] = None, salt=0) -> jax.Array:
     """``x (..., K) @ w (K, N)`` through the operator domain ``op``.
+
+    With ``fi``, ``w`` may be a :class:`~repro.kernels.ops.QuantizedWeight`
+    prepared ahead (``repro.models.transformer.quantize_faulted_weights``);
+    ``aged_linear`` then skips the weight's quantise.
 
     Outputs pass :func:`~repro.distributed.sharding.constrain_replicated`
     — a no-op except under a serve-mesh scope, where pinning every op
@@ -117,7 +122,9 @@ def op_linear(x: jax.Array, w: jax.Array, op: str,
     if jnp.ndim(ber) == 1:
         mesh = axis = None
         if fi.fused and fi.use_systolic_kernel:
-            info = serve_shard_map_info(w.shape[-1])
+            n_out = (w.q if isinstance(w, kops.QuantizedWeight)
+                     else w).shape[-1]
+            info = serve_shard_map_info(n_out)
             if info is not None and info[2] == int(ber.shape[0]):
                 mesh, axis = info[0], info[1]
         return constrain_replicated(kops.aged_linear(
@@ -134,14 +141,16 @@ def op_linear(x: jax.Array, w: jax.Array, op: str,
         use_kernel=fi.use_systolic_kernel, fused=False))
 
 
-def op_einsum(spec: str, x: jax.Array, w: jax.Array, op: str,
+def op_einsum(spec: str, x: jax.Array, w, op: str,
               fi: Optional[FaultConfig] = None, salt=0) -> jax.Array:
     """Einsum variant for fused head layouts; falls back to 2-D for faults.
 
     Supports specs whose contraction letters form a *suffix* of the x spec
     and a *prefix* of the w spec (all uses here: "bsd,dhk->bshk",
     "bshk,hkd->bsd") — the faulted path flattens both to one 2-D systolic
-    matmul, matching how the accelerator executes the fused layout.
+    matmul, matching how the accelerator executes the fused layout.  A
+    :class:`~repro.kernels.ops.QuantizedWeight` already holds that
+    ``(K, N)`` view and passes through as it is.
     """
     if fi is None:
         return constrain_replicated(jnp.einsum(spec, x, w))
@@ -150,13 +159,13 @@ def op_einsum(spec: str, x: jax.Array, w: jax.Array, op: str,
     contract = [c for c in x_spec if c in w_spec]
     nc = len(contract)
     assert x_spec[-nc:] == w_spec[:nc] == "".join(contract), spec
-    k = 1
-    for d in w.shape[:nc]:
-        k *= d
-    x2 = x.reshape(*x.shape[:x.ndim - nc], k)
-    w2 = w.reshape(k, -1)
-    out = op_linear(x2, w2, op, fi, salt)
-    return out.reshape(*x.shape[:x.ndim - nc], *w.shape[nc:])
+    lead, k = x.shape[:x.ndim - nc], math.prod(x.shape[x.ndim - nc:])
+    if isinstance(w, kops.QuantizedWeight):
+        w2, out_dims = w, w.out_dims
+    else:
+        w2, out_dims = w.reshape(k, -1), w.shape[nc:]
+    out = op_linear(x.reshape(*lead, k), w2, op, fi, salt)
+    return out.reshape(*lead, *out_dims)
 
 
 def op_batched_matmul(a: jax.Array, b: jax.Array, op: str,

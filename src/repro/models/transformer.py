@@ -11,6 +11,7 @@ live in :mod:`repro.models.encdec` on top of the same block functions.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs import ModelConfig
 from repro.distributed.sharding import constrain_replicated
+from repro.kernels.ops import QuantizedWeight, quantize_weight
 from . import attention as attn_lib
 from .layers import (FaultConfig, apply_rope, init_norm, mlp_apply, mlp_init,
                      norm, op_einsum, op_linear, rms_norm)
@@ -137,6 +139,55 @@ def dequant_tree(tree, dtype=jnp.bfloat16):
         lambda x: (x["int8_q"].astype(dtype) * x["int8_s"].astype(dtype)
                    if _is_qleaf(x) else x),
         tree, is_leaf=lambda x: _is_qleaf(x) or not isinstance(x, dict))
+
+
+# Faulted weights quantised once per generate call: each weight matmul's
+# leaf -> the number of its leading dims the matmul contracts.
+_FAULTED_WEIGHTS = {"attn": {"wq": 1, "wk": 1, "wv": 1, "wo": 2},
+                    "ffn": {"w_gate": 1, "w_up": 1, "w_down": 1}}
+
+
+def _quantize_weight(w, n_contract: int, stacked: bool):
+    """One float leaf -> its ``QuantizedWeight`` in the ``(K, N)`` view
+    that ``op_einsum`` / ``op_linear`` build, over the stacked group axis
+    when ``stacked``; an HC3 int8 leaf as it is."""
+    if _is_qleaf(w):
+        return w
+    lead = w.shape[:int(stacked)]
+    cut = len(lead) + n_contract
+    out_dims = w.shape[cut:]
+    w2 = w.reshape(*lead, -1, math.prod(out_dims))
+    return QuantizedWeight(*quantize_weight(w2), out_dims)
+
+
+def _quantize_block(bp: Dict, stacked: bool) -> Dict:
+    out = dict(bp)
+    for part, leaves in _FAULTED_WEIGHTS.items():
+        sub = bp.get(part)
+        if not isinstance(sub, dict) or "w_router" in sub:   # MoE: float
+            continue
+        out[part] = {n: (_quantize_weight(w, leaves[n], stacked)
+                         if n in leaves else w) for n, w in sub.items()}
+    return out
+
+
+def quantize_faulted_weights(params: Dict) -> Dict:
+    """Quantise every attention projection (``wq``/``wk``/``wv``/``wo``)
+    and dense FFN weight of ``groups`` and ``tail`` to a
+    :class:`~repro.kernels.ops.QuantizedWeight`, bit-identical to the
+    in-place ``quantize_weight(w2)`` of ``aged_linear``; the faulted
+    matmuls then skip it.  Every other leaf stays as it is and keeps the
+    in-place quantise: MoE FFNs, RG-LRU, RWKV, encoder-decoder trees,
+    HC3 int8 leaves, the unfaulted embedding and head.  Under a serve mesh
+    the int8 leaves inherit the float weights' column sharding."""
+    out = dict(params)
+    if "groups" in params:
+        out["groups"] = {k: _quantize_block(bp, stacked=True)
+                         for k, bp in params["groups"].items()}
+    if "tail" in params:
+        out["tail"] = [{k: _quantize_block(bp, stacked=False)
+                        for k, bp in blk.items()} for blk in params["tail"]]
+    return out
 
 
 # --------------------------------------------------------------------------- #

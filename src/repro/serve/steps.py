@@ -196,7 +196,13 @@ def make_generate_fn(cfg: ModelConfig, max_len: int, n_steps: int,
       — the same derivation the eager oracle performs, so token sequences
       are bit-exact between the two paths;
     * fault streams per step come from ``fi.for_step(t)`` — in-trace
-      integer folds, no materialised randoms, no per-step retrace.
+      integer folds, no materialised randoms, no per-step retrace;
+    * with ``fi``, the faulted layer weights are quantised to int8 once
+      per call, ahead of the prefill
+      (:func:`repro.models.transformer.quantize_faulted_weights`), and the
+      prefill and every decode step read those: the loop quantises
+      activations only.  The eager per-token oracle quantises in place;
+      the two give the same bits.
 
     Tokens generated past a ring-buffered (windowed) cache's capacity
     follow the same ring semantics as the eager loop (both call the same
@@ -219,6 +225,8 @@ def make_generate_fn(cfg: ModelConfig, max_len: int, n_steps: int,
                 # hoist the per-op threefry stream bases out of the scan
                 # body: in-loop derivation is then pure fmix32 integer folds
                 fi = fi.with_seeds()
+                # and the weights' int8 quantise: once per call, not per step
+                params = tf.quantize_faulted_weights(params)
             out = prefill(params, prompts, fi, *extras)
             logits, cache = out[0], out[1]
             kv = out[2] if has_kv else None
