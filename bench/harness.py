@@ -1,7 +1,8 @@
 """One run of one cell: set-up, the measured window, the comparison with
 the reference, and the metrics.
 
-The window is a closed loop of ``ServeEngine.generate`` calls, one after
+The window is a closed loop of the engine's ``generate`` calls (a
+``ServeEngine``, or a ``MeshServeEngine`` on several chips), one after
 another, each on fresh uniform-token prompts drawn on the host from the
 seed.  A request is due when its call starts and done when the call
 returns its tokens.  In an aged cell every ``fault_free_every``-th call
@@ -12,6 +13,7 @@ and the others are checked to be upset at all.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import json
 import math
@@ -29,7 +31,6 @@ import program
 import reference
 import tracefile
 import weights
-from dims import read_dims
 
 TRACE_DIR = "trace"             # under the run's work directory
 
@@ -41,7 +42,16 @@ class Call:
     t_due: float
     t_done: float
     fault_free: bool
-    bers: dict
+    bers: object            # {op: BER}, or a mesh engine's (S, O) array
+    operators: tuple = None  # the columns of a mesh engine's ``bers``
+
+
+def served_bers(call: Call) -> dict:
+    """{op: (BER of each shard, ...)} that a call was served at."""
+    if call.operators is None:
+        return {op: (float(b),) for op, b in call.bers.items()}
+    return {op: tuple(float(b) for b in call.bers[:, i])
+            for i, op in enumerate(call.operators)}
 
 
 def seed_rng(seed: int, stream: int) -> np.random.Generator:
@@ -94,8 +104,8 @@ def verdict(checks: dict) -> bool:
     return all(v <= lim for v, lim in checks.values())
 
 
-def check(cell: dict, dims, seed: int, calls: List[Call], compiles: dict,
-          controls=()) -> tuple:
+def check(cell: dict, fam, dims, seed: int, calls: List[Call],
+          compiles: dict, controls=()) -> tuple:
     """The numbers compared with their limits: ({name: (value, limit)},
     one such dict per control, further readings that set limits).  A
     control is the reference at a lower precision (``controls``) put in the
@@ -114,7 +124,9 @@ def check(cell: dict, dims, seed: int, calls: List[Call], compiles: dict,
         p_f, s_f = _sample(rng, faulted, traffic["check_faulted_requests"])
         prompts = np.concatenate([p_exact, p_f])
         served = np.concatenate([s_exact, s_f])
-    r = reference.readings(dims, seed, prompts, served, controls=controls)
+    r = reference.readings(fam, dims, seed, prompts, served,
+                           controls=controls,
+                           chips=cell["workload"].get("chips", 1))
     best = r["best"][:n_exact]
     # the widest shortfall, and the mean one: a token chosen against a
     # logit error e falls short by about e where the race was within e,
@@ -130,13 +142,16 @@ def check(cell: dict, dims, seed: int, calls: List[Call], compiles: dict,
     if faulted:
         top1 = float(np.mean(served[n_exact:] == r["argmax"][n_exact:]))
         out["faulted_top1"] = (top1, limits["faulted_top1"])
+        # the stated BER of each shard, or one for every shard
         stated = traffic["device"]["ber"]
         worst = 0.0
         for c in faulted:
+            got_bers = served_bers(c)
             for op, ber in stated.items():
-                # a BER served as 0 reads as some 300 decades off
-                got = max(c.bers.get(op, 0.0), 1e-300)
-                worst = max(worst, abs(math.log10(got / ber)))
+                got = got_bers.get(op, (0.0,))
+                for g, b in zip(got, np.broadcast_to(ber, len(got))):
+                    # a BER served as 0 reads as some 300 decades off
+                    worst = max(worst, abs(math.log10(max(g, 1e-300) / b)))
         out["ber_decades"] = (worst, traffic["device"]["ber_decades"])
     out["window_compiles"] = (float(sum(compiles.values())), 0.0)
     # what faulted_top1 would read with the upsets left out: the share of
@@ -169,7 +184,7 @@ def window(engine, fault_free, traffic: dict, vocab: int, seed: int,
             t_done = time.perf_counter()
             with TraceAnnotation("harvest"):
                 calls.append(Call(prompts, res.tokens, t_due, t_done, free,
-                                  res.bers))
+                                  res.bers, getattr(res, "operators", None)))
             i += 1
         t1 = time.perf_counter()
     return calls, t0, t1
@@ -182,35 +197,61 @@ def _malformed(call: Call, n: int, vocab: int) -> int:
     return int(np.sum((t < 0).any(1) | (t >= vocab).any(1)))
 
 
+@dataclasses.dataclass
+class Served:
+    """A cell's model and engines, built from the seed and warmed."""
+    fam: object
+    dims: object
+    devices: list
+    engine: object
+    fault_free: object       # the BER-0 twin, or None for a clean cell
+
+
+def set_up(cell: dict, seed: int) -> Served:
+    """The cell's family, sizes and weights, its engines on its chips, and
+    one warm call of the window's shape.  On several chips every weight is
+    made in place, each chip's share on that chip."""
+    traffic = cell["traffic"]
+    fam = cells.family(cell["config"])
+    dims = fam.read_dims(cell["config_name"], cell["config"])
+    devices = jax.devices()[:cell["workload"].get("chips", 1)]
+    cfg = fam.model_config(dims)
+    mesh = place = None
+    if len(devices) > 1:
+        mesh = program.serve_mesh(devices)
+        place = functools.partial(program.param_shardings, cfg, mesh)
+    params = weights.build_params(fam, dims, seed, place)
+    B, S, N = (traffic["batch"], traffic["prompt_tokens"],
+               traffic["new_tokens"])
+    engine, fault_free = program.engines(cfg, params, traffic["device"],
+                                         max_len=S + N + 1,
+                                         seed=seed % 2 ** 31, mesh=mesh)
+    warm = seed_rng(seed, 0).integers(0, dims.vocab, (B, S), dtype=np.int32)
+    engine.generate(warm, N)
+    if fault_free is not None:
+        fault_free._fault_config()
+    return Served(fam, dims, devices, engine, fault_free)
+
+
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              t_start: float, workdir: str, controls=(),
              log=print) -> dict:
     """Everything after the look for a chip.  Returns the result object,
     with the compared numbers under ``checks``."""
     traffic = cell["traffic"]
-    dims = read_dims(cell["config_name"], cell["config"])
     program.enable_compile_cache()
     # every program, however quick to compile, comes from the cache
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     watch = CompileWatch()
-    devices = jax.devices()[:cell["workload"].get("chips", 1)]
+    sv = set_up(cell, seed)
+    fam, dims, devices = sv.fam, sv.dims, sv.devices
     peak_table = peaks(devices[0].device_kind) if trace else None
-
-    cfg = program.model_config(dims)
-    params = weights.build_params(dims, seed)
     B, S, N = (traffic["batch"], traffic["prompt_tokens"],
                traffic["new_tokens"])
-    engine, fault_free = program.engines(cfg, params, traffic["device"],
-                                         max_len=S + N + 1,
-                                         seed=seed % 2 ** 31)
-    warm = seed_rng(seed, 0).integers(0, dims.vocab, (B, S), dtype=np.int32)
-    engine.generate(warm, N)
-    if fault_free is not None:
-        fault_free._fault_config()
     setup_s = time.perf_counter() - t_start
     log(f"[bench] set-up {setup_s:.3f} s: {dims.name} {dims.n_layers} "
         f"layers, batch {B} x prompt {S} + {N} new, "
-        f"route {traffic['device']['route']}")
+        f"route {traffic['device']['route']}, {len(devices)} chip(s)")
 
     trace_dir = None
     if trace:
@@ -218,8 +259,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
     before = watch.snapshot()
-    calls, t0, t1 = window(engine, fault_free, traffic, dims.vocab, seed,
-                           seconds)
+    calls, t0, t1 = window(sv.engine, sv.fault_free, traffic, dims.vocab,
+                           seed, seconds)
     compiles = CompileWatch.delta(before, watch.snapshot())
     if trace:
         jax.profiler.stop_trace()
@@ -229,15 +270,15 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     log(f"[bench] call seconds: first {took[0]:.4f}, median "
         f"{float(np.median(took)):.4f}, slowest {max(took):.4f}")
     peak = _peak_bytes(devices)
-    del engine, fault_free, params
+    del sv
     gc.collect()
 
     lat = np.asarray([c.t_done - c.t_due for c in calls
                       for _ in range(c.tokens.shape[0])])
     failed = sum(_malformed(c, N, dims.vocab) for c in calls)
     ok_calls = [c for c in calls if _malformed(c, N, dims.vocab) == 0]
-    checks, control_checks, readings = check(cell, dims, seed, ok_calls,
-                                             compiles, controls)
+    checks, control_checks, readings = check(cell, fam, dims, seed,
+                                             ok_calls, compiles, controls)
     dev = devices[0]
     result = {
         "correct": failed == 0 and verdict(checks),
@@ -261,7 +302,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
             tr = tracefile.load(trace_dir)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = Context(tr, dims, traffic, peak_table)
+        ctx = Context(tr, dims, traffic, peak_table, len(devices))
         for m in cell["per_layer"]:
             v = cells.metric_reader(m["name"])(ctx)
             if v is None:
@@ -292,8 +333,10 @@ class Context:
     dims: object
     traffic: dict
     peak: dict
+    chips: int = 1           # the trace is the first chip's
 
-    GENERATE = r"jit_generate"
+    # one chip's generate program, or the one over a mesh
+    GENERATE = r"jit_generate|jit_sharded_gen"
 
     @property
     def aged(self) -> bool:

@@ -3,7 +3,9 @@
 The least time the chip needs for the faulted weight matmuls of the
 generate calls wholly inside the traced window, each matmul bound by int8
 operations or by HBM bytes counted from the model's shapes, over the
-summed device time of the kernel's events inside those calls."""
+summed device time of the kernel's events inside those calls.  On several
+chips both are the first chip's: its column block of each matmul, and its
+own kernel events."""
 import costs
 import tracefile
 
@@ -19,5 +21,5 @@ def read(ctx):
         return None
     floor = len(progs) * costs.aged_matmul_floor_s(
         ctx.dims, *ctx.call_shape(), peak_ops=ctx.peak["int8_ops_per_s"],
-        peak_bytes=ctx.peak["hbm_bytes_per_s"])
+        peak_bytes=ctx.peak["hbm_bytes_per_s"], chips=ctx.chips)
     return 100.0 * floor / (kernel_ns * 1e-9)

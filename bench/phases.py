@@ -97,40 +97,33 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("phases: needs a TPU", file=sys.stderr)
         return 3
-    import numpy as np
+    if cell["workload"]["chips"] != 1:
+        # generate_hlo lowers the one-chip engine's program
+        print("phases: reads one-chip cells only", file=sys.stderr)
+        return 3
 
     import harness
     import program
     import scopes
     import tracefile
-    import weights
-    from dims import read_dims
 
     traffic = cell["traffic"]
-    dims = read_dims(cell["config_name"], cell["config"])
     program.enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     B, S, N = (traffic["batch"], traffic["prompt_tokens"],
                traffic["new_tokens"])
-    params = weights.build_params(dims, args.seed)
-    engine, fault_free = program.engines(
-        program.model_config(dims), params, traffic["device"],
-        max_len=S + N + 1, seed=args.seed % 2 ** 31)
-    warm = harness.seed_rng(args.seed, 0).integers(0, dims.vocab, (B, S),
-                                                   dtype=np.int32)
-    engine.generate(warm, N)
-    if fault_free is not None:
-        fault_free._fault_config()
+    sv = harness.set_up(cell, args.seed)
+    engine, fault_free, vocab = sv.engine, sv.fault_free, sv.dims.vocab
 
     def median_call_s(calls):
         return statistics.median(c.t_done - c.t_due for c in calls)
 
-    untraced, _, _ = harness.window(engine, fault_free, traffic, dims.vocab,
+    untraced, _, _ = harness.window(engine, fault_free, traffic, vocab,
                                     args.seed, args.seconds)
     trace_dir = os.path.join(cells.ROOT, ".bench", "phases_trace")
     shutil.rmtree(trace_dir, ignore_errors=True)
     jax.profiler.start_trace(trace_dir)
-    traced, _, _ = harness.window(engine, fault_free, traffic, dims.vocab,
+    traced, _, _ = harness.window(engine, fault_free, traffic, vocab,
                                   args.seed, args.seconds)
     jax.profiler.stop_trace()
     paths = scopes.op_paths(generate_hlo(engine, B, S, N))

@@ -1,11 +1,13 @@
-"""The plain reference: a decoder-only transformer in float32, written from
-the published architecture.
+"""The plain reference's parts that every family shares, in float32: matrix
+products, norms, rotary embedding, activations and causal attention.  A
+family module (``families/``) writes its block, embedding and head from
+these, after the published architecture.
 
 No kernels, no cache, no batching tricks: the whole sequence goes through
 every layer at once, with every matrix product at ``Precision.HIGHEST``
 (a TPU otherwise runs float32 products in bfloat16 passes).  It imports
-nothing of the serving program; its weights come from ``weights.py``, the
-benchmark's own generator.
+nothing of the serving program; its weights come from the family's
+generator.
 
 ``prec`` lowers chosen products to symmetric integer arithmetic, for the
 control that decides whether a limit can tell a lower precision apart:
@@ -37,7 +39,10 @@ def quantize(x, axis: int, bits: int):
 
 def matmul(a, b, bits: Optional[int] = None):
     """``a @ b`` in float32, or in ``bits``-bit integers (a per row, b per
-    column, both over the contracted axis)."""
+    column, both over the contracted axis).  ``b`` is cast to float32
+    here, one matrix at a time, so a layer's weights stay in their own
+    type until each is used."""
+    b = b.astype(jnp.float32)
     if bits is None:
         return jnp.matmul(a, b, precision=HIGHEST)
     qa, sa = quantize(a, -1, bits)
@@ -100,37 +105,3 @@ def attention(q, k, v, window: Optional[int], bits: Optional[int]):
     out = jax.lax.map(one, (qc, starts))                      # c n H Q hd
     out = out.transpose(1, 2, 0, 3, 4).reshape(n, H, -1, hd)[:, :, :T]
     return out.transpose(0, 2, 1, 3)                           # n T H hd
-
-
-def block(x, w, dims, prec):
-    """One layer on hidden states x (n, T, d)."""
-    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-    n, T, d = x.shape
-    H, KV, hd = dims.n_heads, dims.n_kv_heads, dims.head_dim
-    bits = prec["linear"]
-    a = w["attn"]
-    h = norm(x, w["norm1"], dims.norm, dims.norm_eps)
-    q = matmul(h, a["wq"].reshape(d, H * hd), bits).reshape(n, T, H, hd)
-    k = matmul(h, a["wk"].reshape(d, KV * hd), bits).reshape(n, T, KV, hd)
-    v = matmul(h, a["wv"].reshape(d, KV * hd), bits).reshape(n, T, KV, hd)
-    q, k = rope(q, dims.rope_theta), rope(k, dims.rope_theta)
-    o = attention(q, k, v, dims.window, prec["attn"])
-    x = x + matmul(o.reshape(n, T, H * hd), a["wo"].reshape(H * hd, d), bits)
-    h = norm(x, w["norm2"], dims.norm, dims.norm_eps)
-    f = w["ffn"]
-    if dims.mlp == "gated":
-        u = jax.nn.silu(matmul(h, f["w_gate"], bits)) \
-            * matmul(h, f["w_up"], bits)
-    else:
-        u = gelu_tanh(matmul(h, f["w_up"], bits))
-    return x + matmul(u, f["w_down"], bits)
-
-
-def embed(table, tokens):
-    return table[tokens].astype(jnp.float32)
-
-
-def head_logits(x, outer, dims, prec):
-    """Final norm and lm_head: x (n, P, d) -> logits (n, P, V)."""
-    h = norm(x, outer["final_norm"], dims.norm, dims.norm_eps)
-    return matmul(h, outer["lm_head"].astype(jnp.float32), prec["head"])

@@ -12,12 +12,13 @@ BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 
 TINY = {
-    "llama": {"hidden_act": "silu", "hidden_size": 64,
+    "llama": {"model_type": "llama", "hidden_act": "silu", "hidden_size": 64,
               "intermediate_size": 128, "num_attention_heads": 4,
               "num_hidden_layers": 2, "num_key_value_heads": 4,
               "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
               "vocab_size": 256},
-    "starcoder2": {"hidden_act": "gelu_pytorch_tanh", "hidden_size": 64,
+    "starcoder2": {"model_type": "starcoder2",
+                   "hidden_act": "gelu_pytorch_tanh", "hidden_size": 64,
                    "intermediate_size": 128, "num_attention_heads": 4,
                    "num_hidden_layers": 2, "num_key_value_heads": 2,
                    "norm_type": "layer_norm", "norm_epsilon": 1e-5,

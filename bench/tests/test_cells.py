@@ -9,7 +9,6 @@ import sys
 import pytest
 
 import cells
-from dims import read_dims
 
 BENCH = json.load(open(os.path.join(cells.ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -18,7 +17,8 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 @pytest.mark.parametrize("w", [w["name"] for w in BENCH["workloads"]])
 def test_cell_resolves(w):
     cell = cells.load_cell(w)
-    dims = read_dims(cell["config_name"], cell["config"])
+    dims = cells.family(cell["config"]).read_dims(cell["config_name"],
+                                                  cell["config"])
     t = cell["traffic"]
     assert t["batch"] > 0 and t["prompt_tokens"] > 0 and t["new_tokens"] > 1
     assert dims.n_heads % dims.n_kv_heads == 0

@@ -117,9 +117,10 @@ def test_compile_in_window_is_caught(cell_factory, monkeypatch, tmp_path):
 
 
 
-SMALL_LLAMA = {"hidden_act": "silu", "hidden_size": 512,
-               "intermediate_size": 1024, "num_attention_heads": 4,
-               "num_hidden_layers": 2, "num_key_value_heads": 4,
+SMALL_LLAMA = {"model_type": "llama", "hidden_act": "silu",
+               "hidden_size": 512, "intermediate_size": 1024,
+               "num_attention_heads": 4, "num_hidden_layers": 2,
+               "num_key_value_heads": 4,
                "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
                "vocab_size": 8192}
 

@@ -49,9 +49,10 @@ def test_programs_and_ops_within():
 
 
 class Ctx:
-    def __init__(self, tr, aged=True):
-        from dims import Dims
+    def __init__(self, tr, aged=True, chips=1):
+        from families.decoder import Dims
         self.trace = tr
+        self.chips = chips
         self.dims = Dims(name="t", n_layers=1, d_model=8, n_heads=2,
                          n_kv_heads=1, head_dim=4, d_ff=16, vocab=32,
                          mlp="gated", norm="rms", norm_eps=1e-6,
