@@ -32,11 +32,16 @@ class ModelConfig:
     head_dim: Optional[int] = None   # default d_model // n_heads
     moe: Optional[MoEConfig] = None
     mlp: str = "gated"               # gated (SwiGLU) | plain (GELU)
-    norm: str = "rms"                # rms | ln
+    norm: str = "rms"                # rms | ln | ln_nobias
     pos: str = "rope"                # rope | learned | none
     rope_theta: float = 10000.0
-    qk_norm: bool = False
+    rope_interleaved: bool = False   # rotate pairs (2i, 2i+1) (GPT-J form)
+    # norm of q and k over head_dim, in the model's norm kind: None, one
+    # weight for every head ("shared", Qwen3) or one per head ("per_head")
+    qk_norm: Optional[str] = None
+    parallel_block: bool = False     # x + attn(n(x)) + mlp(n(x)), one norm
     tie_embeddings: bool = False
+    logit_scale: float = 1.0         # logits = logit_scale * head(x)
     block_pattern: Tuple[str, ...] = ("attn",)   # hybrid: ("rec","rec","attn")
     window: Optional[int] = None     # sliding-window attention size
     n_encoder_layers: int = 0        # enc-dec (whisper)

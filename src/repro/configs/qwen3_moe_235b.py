@@ -7,5 +7,5 @@ CONFIG = ModelConfig(
     n_layers=94, d_model=4096, n_heads=64, n_kv_heads=4,
     d_ff=1536, vocab=151936,
     moe=MoEConfig(n_experts=128, top_k=8),
-    mlp="gated", norm="rms", pos="rope", qk_norm=True, rope_theta=1e6,
+    mlp="gated", norm="rms", pos="rope", qk_norm="shared", rope_theta=1e6,
 )

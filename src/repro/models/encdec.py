@@ -144,8 +144,9 @@ def cross_kv(params, cfg: ModelConfig, enc_out, *,
 
 def decode(params, cfg: ModelConfig, tokens, enc_out=None, kv=None, *,
            fi: Optional[FaultConfig] = None, cache=None, cache_len=None,
-           pos_offset=0, remat: bool = False):
-    """Teacher-forced decoder (full seq) or single-step (with cache)."""
+           pos_offset=0, remat: bool = False, last_only: bool = False):
+    """Teacher-forced decoder (full seq) or single-step (with cache).
+    ``last_only`` (prefill) computes the head at the last position alone."""
     if kv is None:
         kv = cross_kv(params, cfg, enc_out, fi=fi)
     x = params["embed"][tokens]
@@ -179,9 +180,12 @@ def decode(params, cfg: ModelConfig, tokens, enc_out=None, kv=None, *,
     x, new_cache = _scan(
         step, x, (params["dec_layers"], kv, dummy_cache,
                   jnp.arange(cfg.n_layers)), cfg.n_layers)
-    x = norm(x, params["final_norm"], cfg.norm)
-    logits = constrain_replicated(
-        (x @ params["lm_head"]).astype(jnp.float32))
+    if last_only:
+        x = x[:, -1:]
+    with jax.named_scope("head"):
+        x = norm(x, params["final_norm"], cfg.norm)
+        logits = constrain_replicated(
+            (x @ params["lm_head"]).astype(jnp.float32))
     return logits, (new_cache if cache is not None else None)
 
 

@@ -223,6 +223,8 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array | None = None,
 
 
 def norm(x: jax.Array, p: Dict, kind: str) -> jax.Array:
+    """``kind`` "rms", or a LayerNorm ("ln", "ln_nobias") with the bias
+    ``p`` holds, if any."""
     if kind == "rms":
         return rms_norm(x, p["scale"])
     return layer_norm(x, p["scale"], p.get("bias"))
@@ -240,14 +242,26 @@ def rope_frequencies(hd: int, theta: float) -> jax.Array:
     return theta ** (-jnp.arange(0, hd // 2, dtype=jnp.float32) / (hd // 2))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               interleaved: bool = False) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+
+    Rotates the two halves of each head together (``rotate_half``), or,
+    ``interleaved``, each pair (2i, 2i+1) by the i-th angle (GPT-J,
+    Cohere)."""
     hd = x.shape[-1]
     freqs = rope_frequencies(hd, theta)                       # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs    # (..., S, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
     return out.astype(x.dtype)
 
 

@@ -22,7 +22,7 @@ from repro.distributed.sharding import constrain_replicated
 from repro.kernels.ops import QuantizedWeight, quantize_weight
 from . import attention as attn_lib
 from .layers import (FaultConfig, apply_rope, init_norm, mlp_apply, mlp_init,
-                     norm, op_einsum, op_linear, rms_norm)
+                     norm, op_einsum, op_linear)
 from .moe import moe_apply, moe_init
 from .rglru import rglru_block, rglru_init, rglru_init_state
 from .rwkv6 import (rwkv_channel_mix, rwkv_channel_mix_init, rwkv_init_state,
@@ -43,16 +43,18 @@ def _attn_init(key, cfg: ModelConfig, dtype) -> Dict:
         "wo": jax.random.normal(ks[3], (H, hd, d), dtype) * (H * hd) ** -0.5,
     }
     if cfg.qk_norm:
-        p["q_norm"] = jnp.ones((hd,), dtype)
-        p["k_norm"] = jnp.ones((hd,), dtype)
+        per_head = cfg.qk_norm == "per_head"
+        p["q_norm"] = jnp.ones((H, hd) if per_head else (hd,), dtype)
+        p["k_norm"] = jnp.ones((KV, hd) if per_head else (hd,), dtype)
     return p
 
 
 def _block_init(key, kind: str, cfg: ModelConfig, dtype) -> Dict:
     k1, k2, k3 = jax.random.split(key, 3)
     d, f = cfg.d_model, cfg.d_ff
-    p = {"norm1": init_norm(cfg.norm, d, dtype),
-         "norm2": init_norm(cfg.norm, d, dtype)}
+    p = {"norm1": init_norm(cfg.norm, d, dtype)}
+    if not (kind == "attn" and cfg.parallel_block):
+        p["norm2"] = init_norm(cfg.norm, d, dtype)
     if kind == "attn":
         p["attn"] = _attn_init(k1, cfg, dtype)
         p["ffn"] = (moe_init(k2, d, f, cfg.moe, cfg.mlp, dtype) if cfg.moe
@@ -197,6 +199,10 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                 cache=None, cache_len=None, fi=None, salt=0):
     """Self-attention + FFN block.  With ``cache`` (decode): single token.
 
+    Sequential (``x += attn(n1(x)); x += ffn(n2(x))``), or with
+    ``cfg.parallel_block`` both branches read one norm of the same input:
+    ``x + attn(n1(x)) + ffn(n1(x))``.
+
     Each attention call runs under ``jax.named_scope("attention")``, which
     names its ops (scores, softmax, SV, their injection) in the metadata
     a profiler trace's reduction reads."""
@@ -205,11 +211,12 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
     q = op_einsum("bsd,dhk->bshk", h, ap["wq"], "q", fi, salt)
     k = op_einsum("bsd,dhk->bshk", h, ap["wk"], "k", fi, salt)
     v = op_einsum("bsd,dhk->bshk", h, ap["wv"], "v", fi, salt)
-    if cfg.qk_norm:
-        q, k = rms_norm(q, ap["q_norm"]), rms_norm(k, ap["k_norm"])
+    if cfg.qk_norm:      # over head_dim; a (heads, hd) weight is per head
+        q = norm(q, {"scale": ap["q_norm"]}, cfg.norm)
+        k = norm(k, {"scale": ap["k_norm"]}, cfg.norm)
     if cfg.pos == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleaved)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleaved)
 
     new_cache = None
     if cache is not None:
@@ -267,7 +274,7 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                                      prefix_len=prefix_len, fi=fi, salt=salt)
     x = x + op_einsum("bshk,hkd->bsd", out, ap["wo"], "o", fi, salt)
 
-    h2 = norm(x, bp["norm2"], cfg.norm)
+    h2 = h if cfg.parallel_block else norm(x, bp["norm2"], cfg.norm)
     if cfg.moe:
         y, aux = moe_apply(h2, bp["ffn"], cfg.moe, cfg.mlp, fi, salt)
     else:
@@ -406,21 +413,36 @@ def unembed(params, cfg: ModelConfig, x):
     w = dequant_tree({"w": w}, x.dtype)["w"]
     if cfg.tie_embeddings:
         w = w.T
-    return constrain_replicated((x @ w).astype(jnp.float32))
+    logits = (x @ w).astype(jnp.float32)
+    if cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
+    return constrain_replicated(logits)
+
+
+def apply_head(params, cfg: ModelConfig, x):
+    """Final norm, unembed and logit scale, under ``jax.named_scope("head")``
+    (it names the ops for a profiler trace's reduction)."""
+    with jax.named_scope("head"):
+        return unembed(params, cfg, norm(x, params["final_norm"], cfg.norm))
 
 
 def forward_logits(params, cfg: ModelConfig, tokens, *, prefix_embeds=None,
                    fi: Optional[FaultConfig] = None,
-                   states=None, cache_len=None, remat=False):
-    """Full-sequence forward (train / prefill).  tokens: (B, S_text)."""
+                   states=None, cache_len=None, remat=False,
+                   last_only: bool = False):
+    """Full-sequence forward (train / prefill).  tokens: (B, S_text).
+
+    ``last_only`` (prefill) computes the head at the last position alone:
+    logits (B, 1, V) in place of (B, S, V)."""
     x = embed_tokens(params, cfg, tokens, prefix_embeds)
     S = x.shape[1]
     positions = jnp.arange(S)[None, :]
     x, new_states, aux = _run_blocks(
         x, params, cfg, positions=positions, prefix_len=cfg.prefix_tokens,
         states=states, cache_len=cache_len, fi=fi, remat=remat)
-    x = norm(x, params["final_norm"], cfg.norm)
-    return unembed(params, cfg, x), new_states, aux
+    if last_only:
+        x = x[:, -1:]
+    return apply_head(params, cfg, x), new_states, aux
 
 
 def quantize_cache_entry(x):
@@ -491,5 +513,4 @@ def decode_step(params, cfg: ModelConfig, token, cache, cache_len, *,
         positions = (cache_len - 1).astype(jnp.int32)[:, None]    # (B, 1)
     x, new_cache, _ = _run_blocks(x, params, cfg, positions=positions,
                                   states=cache, cache_len=cache_len, fi=fi)
-    x = norm(x, params["final_norm"], cfg.norm)
-    return unembed(params, cfg, x), new_cache
+    return apply_head(params, cfg, x), new_cache

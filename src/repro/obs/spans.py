@@ -17,6 +17,10 @@ Span names used by the serving path (``repro.serve.engine``):
   on an accelerator: the enqueue);
 * ``serve.wait`` — the blocking fetch of the generated tokens;
 * ``serve.finish`` — telemetry, served BERs and power, the result.
+
+and by the mesh engine (``repro.serve.sharded``): ``mesh.prepare`` — the
+fault config and the device puts — then ``mesh.generate`` — the dispatch
+and the tokens' return.
 """
 from __future__ import annotations
 

@@ -207,6 +207,8 @@ class MeshServeEngine:
                  top_k: Optional[int] = None) -> MeshGenerateResult:
         """prompts: (B, S) int32 -> ``n_steps`` tokens from ONE dispatch.
 
+        Host span ``mesh.prepare`` covers the fault config and the device
+        puts, ``mesh.generate`` the dispatch and the tokens' return.
         Every runtime input (prompts, FaultConfig leaves, key,
         temperature) enters replicated over the mesh with the same
         NamedSharding on every call, so age advances and shard-BER updates
@@ -215,15 +217,18 @@ class MeshServeEngine:
         prompts and the call key are donated to the executable.
         """
         cfg = self.cfg
-        fi = self._fault_config()
-        self._key, call_key = jax.random.split(self._key)
-        put = lambda t: jax.device_put(t, self._repl)
-        prompts = put(jnp.asarray(prompts, jnp.int32))
-        extras = tuple(put(e) for e in self._extras(prefix_embeds, frames))
-        # fi leaves are already replicated by _fault_config (BERs cached
-        # across calls, key/step put there) — no per-call tree device_put
-        temp = put(ServeEngine._temperature(greedy, temperature))
-        call_key = put(call_key)
+        with span("mesh.prepare"):
+            fi = self._fault_config()
+            self._key, call_key = jax.random.split(self._key)
+            put = lambda t: jax.device_put(t, self._repl)
+            prompts = put(jnp.asarray(prompts, jnp.int32))
+            extras = tuple(put(e)
+                           for e in self._extras(prefix_embeds, frames))
+            # fi leaves are already replicated by _fault_config (BERs
+            # cached across calls, key/step put there) — no per-call tree
+            # device_put
+            temp = put(ServeEngine._temperature(greedy, temperature))
+            call_key = put(call_key)
 
         m0 = _mesh_generate_fn.misses
         gen = _mesh_generate_fn(cfg, self.max_len, int(n_steps), top_k,

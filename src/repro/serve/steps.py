@@ -79,7 +79,8 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
     [, kv]).
 
     The cache is allocated at ``max_len`` so subsequent decode steps reuse
-    it in place.  ``fi`` is a runtime argument (pytree) — one jitted
+    it in place.  The final norm and the head run at the last prompt
+    position only: the one whose logits are sampled.  ``fi`` is a runtime argument (pytree) — one jitted
     instance covers every device age of a fault flavour.
     """
     if cfg.n_encoder_layers:
@@ -97,7 +98,7 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
                                                     jnp.bfloat16))
             logits, cache = encdec.decode(
                 params, cfg, tokens, kv=kv, fi=fi, cache=cache,
-                cache_len=jnp.asarray(S, jnp.int32))
+                cache_len=jnp.asarray(S, jnp.int32), last_only=True)
             return logits[:, -1], cache, kv
         return prefill
 
@@ -109,7 +110,7 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
             logits, cache, _ = tf.forward_logits(
                 params, cfg, tokens, states=cache,
                 cache_len=jnp.asarray(S + cfg.prefix_tokens, jnp.int32),
-                fi=fi, prefix_embeds=prefix_embeds)
+                fi=fi, prefix_embeds=prefix_embeds, last_only=True)
             return logits[:, -1], cache
         return prefill
 
@@ -119,7 +120,7 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
         cache = tf.init_cache(cfg, B, max_len)
         logits, cache, _ = tf.forward_logits(
             params, cfg, tokens, states=cache,
-            cache_len=jnp.asarray(S, jnp.int32), fi=fi)
+            cache_len=jnp.asarray(S, jnp.int32), fi=fi, last_only=True)
         return logits[:, -1], cache
     return prefill
 
