@@ -1,6 +1,7 @@
 """The Cohere family (``families/cohere.py``, Command R+): its configuration
 resolves through the seam; a tiny Cohere cell of the ``chat.tp4`` traffic
-runs correct on one chip and on four virtual CPU devices; each Cohere part
+runs correct on one chip and on four virtual CPU devices, and not correct
+on four with the exchange between the chips left out; each Cohere part
 that shows in served tokens, left out or replaced in the program's place,
 comes out not correct; and
 ``collective_share`` reads its share from a trace made by hand.
@@ -195,18 +196,56 @@ class ShardBers:
         return np.ones(1)
 
 
+from jax.sharding import PartitionSpec as P
+from repro.kernels import ops
+
+SHARD_MAPPED = []       # the matmuls traced through the shard_map route
+sound = ops._fused_aged_matmul_sharded
+
+
+def counted(*args):
+    SHARD_MAPPED.append(1)
+    return sound(*args)
+
+
+def no_gather(xq, wq, bers, seed, mesh, shard_axis, interpret):
+    # the exchange between chips left out: each chip runs the fused kernel
+    # on its own column block and hands that block back, in the first
+    # columns, as the whole (replicated) output.  (Tiled over every block
+    # instead, each chip's own columns would hold its own block, and the
+    # partitioner, slicing them out for the column-split scales of the
+    # epilogue and gathering after it, would serve the sound tokens.)
+    SHARD_MAPPED.append(1)
+    S = int(mesh.shape[shard_axis])
+
+    def body(xq, wq_blk, bers, seed):
+        s = jax.lax.axis_index(shard_axis)
+        acc = ops.fused_aged_matmul(xq, wq_blk, ber=bers[s],
+                                    seed=ops.fold_seed(seed, s),
+                                    interpret=interpret)
+        return jax.numpy.pad(acc, ((0, 0), (0, (S - 1) * acc.shape[1])))
+
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(None, shard_axis), P(), P()),
+                         out_specs=P(), check_vma=False)(
+        xq, wq, jax.numpy.asarray(bers, jax.numpy.float32),
+        jax.numpy.asarray(seed, jax.numpy.int32))
+
+
+ops._fused_aged_matmul_sharded = no_gather if PLANT else counted
 program.aged_fleet = lambda device, chips: ShardBers()
 r = harness.run_cell(tiny_cell(4), 2 ** 31 + 5, 1.5, False,
                      time.perf_counter(), WORK, log=lambda s: None)
 print("RESULT " + json.dumps({"correct": r["correct"],
                               "checks": r["checks"],
-                              "count": r["device"]["count"]}))
+                              "count": r["device"]["count"],
+                              "shard_mapped": len(SHARD_MAPPED)}))
 """
 
 
-def test_four_chips_correct(tmp_path):
+def four_chips(tmp_path, plant: bool) -> dict:
     code = (f"BENCH, HERE, WORK = {BENCH!r}, {HERE!r}, {str(tmp_path)!r}\n"
-            + CHILD)
+            f"PLANT = {plant!r}\n" + CHILD)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     p = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -215,9 +254,25 @@ def test_four_chips_correct(tmp_path):
     assert p.returncode == 0 and lines, p.stderr[-4000:]
     out = json.loads(lines[-1][len("RESULT "):])
     assert out["count"] == 4
+    # the weight matmuls take the shard_map route, sound or planted
+    assert out["shard_mapped"] > 0
+    return out
+
+
+def test_four_chips_correct(tmp_path):
+    out = four_chips(tmp_path, plant=False)
     assert out["correct"], out["checks"]
     assert out["checks"]["window_compiles"]["value"] == 0
     assert out["checks"]["faulted_top1"]["value"] < 0.2
+
+
+def test_four_chips_without_the_gather_is_not_correct(tmp_path):
+    """A shard_map that returns each chip's own column block as the whole
+    output, with no exchange between the chips, comes out not correct."""
+    out = four_chips(tmp_path, plant=True)
+    assert not out["correct"], out["checks"]
+    assert any(out["checks"][k]["value"] > out["checks"][k]["limit"]
+               for k in ("gap", "mean_gap")), out["checks"]
 
 
 def test_collective_share_by_hand():
