@@ -173,10 +173,11 @@ def rwkv_channel_mix(x, p, *, state: Optional[jax.Array] = None,
     return out, (x[:, -1] if state is not None else None)
 
 
-def rwkv_init_state(batch: int, d: int, hd: int) -> Dict:
+def rwkv_init_state(batch: int, d: int, hd: int,
+                    dtype=jnp.bfloat16) -> Dict:
     H = d // hd
     return {
-        "tm": {"shift": jnp.zeros((batch, d), jnp.bfloat16),
+        "tm": {"shift": jnp.zeros((batch, d), dtype),
                "wkv": jnp.zeros((batch, H, hd, hd), jnp.float32)},
-        "cm_shift": jnp.zeros((batch, d), jnp.bfloat16),
+        "cm_shift": jnp.zeros((batch, d), dtype),
     }

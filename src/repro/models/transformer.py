@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs import ModelConfig
 from repro.distributed.sharding import constrain_replicated
 from repro.kernels.ops import QuantizedWeight, quantize_weight
+from repro.obs.metrics import REGISTRY
 from . import attention as attn_lib
 from .layers import (FaultConfig, apply_rope, init_norm, mlp_apply, mlp_init,
                      norm, op_einsum, op_linear)
@@ -27,6 +28,12 @@ from .moe import moe_apply, moe_init
 from .rglru import rglru_block, rglru_init, rglru_init_state
 from .rwkv6 import (rwkv_channel_mix, rwkv_channel_mix_init, rwkv_init_state,
                     rwkv_time_mix, rwkv_time_mix_init)
+
+
+# attention blocks traced with a cache, by how they write it: "token" (a
+# decode step writes one slot) or "slab" (a prefill writes its layer whole).
+# Ticks when the Python body runs, i.e. while jax traces a jitted caller.
+KV_WRITES = REGISTRY.trace_counter("kv_writes")
 
 
 # --------------------------------------------------------------------------- #
@@ -195,13 +202,49 @@ def quantize_faulted_weights(params: Dict) -> Dict:
 # --------------------------------------------------------------------------- #
 # block application
 # --------------------------------------------------------------------------- #
+def _layer_of(buf, layer):
+    """Layer ``layer`` of a stacked state leaf ``(n_groups, ...)``; the leaf
+    itself when ``layer`` is None (an unstacked tail block's state)."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+
+
+def _write_layer(buf, new, layer):
+    """Write one layer's whole state ``new`` into the stack ``buf``."""
+    if layer is None:
+        return new
+    return jax.lax.dynamic_update_index_in_dim(buf, new.astype(buf.dtype),
+                                               layer, 0)
+
+
+def _write_token(buf, new, idx, layer):
+    """Write a decode token ``new`` (B, 1, ...) at ring slot ``idx`` of the
+    layer's ``(B, kv_len, ...)`` ring: one update slice for a scalar
+    ``idx``, a scatter of each row at its own slot for a ``(B,)`` vector."""
+    new = new.astype(buf.dtype)
+    lead = () if layer is None else (layer,)
+    if jnp.ndim(idx):            # per-row depths (continuous-batching slots)
+        rows = jnp.arange(new.shape[0])
+        return buf.at[lead + (rows, idx)].set(new[:, 0])
+    zero = jnp.zeros((), jnp.int32)
+    starts = lead + (zero, idx) + (zero,) * (new.ndim - 2)
+    return jax.lax.dynamic_update_slice(
+        buf, new.reshape((1,) * len(lead) + new.shape), starts)
+
+
 def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
-                cache=None, cache_len=None, fi=None, salt=0):
+                cache=None, cache_len=None, fi=None, salt=0, layer=None):
     """Self-attention + FFN block.  With ``cache`` (decode): single token.
 
     Sequential (``x += attn(n1(x)); x += ffn(n2(x))``), or with
     ``cfg.parallel_block`` both branches read one norm of the same input:
     ``x + attn(n1(x)) + ffn(n1(x))``.
+
+    With ``layer``, ``cache`` is the whole stack ``(n_groups, B, kv_len,
+    KV, hd)`` carried through the layer scan: a decode step writes its
+    token into layer ``layer``'s ring in place and attends over that
+    layer's ring read from the stack, a prefill writes its layer whole.
 
     Each attention call runs under ``jax.named_scope("attention")``, which
     names its ops (scores, softmax, SV, their injection) in the metadata
@@ -222,35 +265,32 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
     if cache is not None:
         qcache = _is_qleaf(cache["k"])   # int8 KV cache (§Perf HC3)
         kbuf = cache["k"]["int8_q"] if qcache else cache["k"]
-        kv_len = kbuf.shape[1]
+        kv_len = kbuf.shape[-3]
         if q.shape[1] == 1:      # decode: ring-write to cache, attend
+            KV_WRITES["token"] += 1
             # ring addressing: token t lives at slot t % kv_len (identity for
             # full-length caches; wraps for windowed local attention)
             idx = jnp.remainder(cache_len - 1, kv_len)
-            if jnp.ndim(idx) == 0:
-                write = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(
-                    buf, new, idx, 1)
-            else:                # per-row depths (continuous-batching slots):
-                                 # each row scatters at its own ring position
-                rows = jnp.arange(k.shape[0])
-                write = lambda buf, new: buf.at[rows, idx].set(new[:, 0])
             if qcache:
                 knew, vnew = quantize_cache_entry(k), quantize_cache_entry(v)
-                kc = {f: write(cache["k"][f], knew[f]) for f in knew}
-                vc = {f: write(cache["v"][f], vnew[f]) for f in vnew}
-                k_at = kc["int8_q"].astype(q.dtype) \
-                    * kc["int8_s"].astype(q.dtype)
-                v_at = vc["int8_q"].astype(q.dtype) \
-                    * vc["int8_s"].astype(q.dtype)
             else:
-                kc = write(cache["k"], k)
-                vc = write(cache["v"], v)
-                k_at, v_at = kc, vc
+                knew, vnew = k, v
+            write = lambda buf, new: _write_token(buf, new, idx, layer)
+            kc = jax.tree.map(write, cache["k"], knew)
+            vc = jax.tree.map(write, cache["v"], vnew)
+            read = lambda buf: _layer_of(buf, layer)
+            if qcache:
+                k_at = read(kc["int8_q"]).astype(q.dtype) \
+                    * read(kc["int8_s"]).astype(q.dtype)
+                v_at = read(vc["int8_q"]).astype(q.dtype) \
+                    * read(vc["int8_s"]).astype(q.dtype)
+            else:
+                k_at, v_at = read(kc), read(vc)
             with jax.named_scope("attention"):
                 out = attn_lib.decode_attention(q, k_at, v_at, cache_len,
                                                 fi=fi, salt=salt)
-            new_cache = {"k": kc, "v": vc}
         else:                    # prefill: run full attn, stash K/V
+            KV_WRITES["slab"] += 1
             with jax.named_scope("attention"):
                 out = attn_lib.attention(q, k, v, causal=True,
                                          window=cfg.window,
@@ -267,7 +307,10 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                 vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
             if qcache:
                 kc, vc = quantize_cache_entry(kc), quantize_cache_entry(vc)
-            new_cache = {"k": kc, "v": vc}
+            write = lambda buf, new: _write_layer(buf, new, layer)
+            kc = jax.tree.map(write, cache["k"], kc)
+            vc = jax.tree.map(write, cache["v"], vc)
+        new_cache = {"k": kc, "v": vc}
     else:
         with jax.named_scope("attention"):
             out = attn_lib.attention(q, k, v, causal=True, window=cfg.window,
@@ -307,21 +350,34 @@ def _rwkv_block(x, bp, cfg: ModelConfig, *, state=None, fi=None, salt=0):
 
 
 def _apply_block(x, bp, kind, cfg, *, positions, prefix_len, state, cache_len,
-                 fi, salt):
+                 fi, salt, layer=None):
+    """One block.  ``state`` is the block's stacked state when ``layer`` is
+    given (see :func:`_run_blocks`): attention writes its slot or slab into
+    it in place, a recurrent state is read and written back whole."""
     if kind == "attn":
         return _attn_block(x, bp, cfg, positions=positions,
                            prefix_len=prefix_len, cache=state,
-                           cache_len=cache_len, fi=fi, salt=salt)
-    if kind == "rec":
-        return _rec_block(x, bp, cfg, state=state, fi=fi, salt=salt)
-    if kind == "rwkv":
-        return _rwkv_block(x, bp, cfg, state=state, fi=fi, salt=salt)
-    raise ValueError(kind)
+                           cache_len=cache_len, fi=fi, salt=salt, layer=layer)
+    block = {"rec": _rec_block, "rwkv": _rwkv_block}.get(kind)
+    if block is None:
+        raise ValueError(kind)
+    st = None if state is None else \
+        jax.tree.map(lambda a: _layer_of(a, layer), state)
+    x, ns, aux = block(x, bp, cfg, state=st, fi=fi, salt=salt)
+    if state is not None:
+        ns = jax.tree.map(lambda a, n: _write_layer(a, n, layer), state, ns)
+    return x, ns, aux
 
 
 def _run_blocks(x, params, cfg: ModelConfig, *, positions, prefix_len=0,
                 states=None, cache_len=None, fi=None, remat=False):
     """Scan the grouped blocks (+ tail); threads per-block state pytrees.
+
+    The stacked group states ride in the scan's carry, not in its xs/ys:
+    each group writes its own layer of the stack in place (a decode step
+    one slot of each ring), so no step slices a layer's state out of the
+    stack or restacks it.  On a serve mesh the carried stack is pinned
+    replicated, as every activation there is.
 
     ``remat=True`` rematerialises each layer group in the backward pass
     (activation checkpointing at group granularity: stored activations are
@@ -335,41 +391,38 @@ def _run_blocks(x, params, cfg: ModelConfig, *, positions, prefix_len=0,
 
     def group_step(carry, inp):
         from repro.distributed.sharding import constrain_activation
-        x, aux = carry
+        x, aux, gstates = carry
         x = constrain_activation(x)   # pin batch sharding across the scan
-        gparams, gstate, gidx = inp
+        gstates = jax.tree.map(constrain_replicated, gstates)  # serve mesh
+        gparams, gidx = inp
         gparams = dequant_tree(gparams, x.dtype)   # no-op unless int8 leaves
-        new_gstate = {}
         for i, kind in enumerate(pat):
             key = f"b{i}_{kind}"
-            st = gstate[key] if have_state else None
             salt = gidx * len(pat) + i
             x, ns, a = _apply_block(x, gparams[key], kind, cfg,
                                     positions=positions,
-                                    prefix_len=prefix_len, state=st,
-                                    cache_len=cache_len, fi=fi, salt=salt)
-            new_gstate[key] = ns if have_state else jnp.zeros((0,))
+                                    prefix_len=prefix_len,
+                                    state=gstates.get(key),
+                                    cache_len=cache_len, fi=fi, salt=salt,
+                                    layer=gidx)
+            if have_state:
+                gstates[key] = ns
             aux = aux + a
-        return (x, aux), new_gstate
+        return (x, aux, gstates), None
 
     new_states = {}
     aux_total = jnp.zeros((), jnp.float32)
     if n_groups:
-        if have_state:
-            gstates = states["groups"]
-        else:
-            gstates = {f"b{i}_{kind}": jnp.zeros((n_groups, 0))
-                       for i, kind in enumerate(pat)}
         step_fn = group_step
         if remat:
             step_fn = jax.checkpoint(
                 group_step,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        (x, aux_total), scanned_states = jax.lax.scan(
-            step_fn, (x, aux_total),
-            (params["groups"], gstates, jnp.arange(n_groups)))
+        (x, aux_total, gstates), _ = jax.lax.scan(
+            step_fn, (x, aux_total, states["groups"] if have_state else {}),
+            (params["groups"], jnp.arange(n_groups)))
         if have_state:
-            new_states["groups"] = scanned_states
+            new_states["groups"] = gstates
     for t, tp in enumerate(params.get("tail", [])):
         tp = dequant_tree(tp, x.dtype)
         (key,) = tp.keys()
@@ -479,7 +532,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         if kind == "rec":
             return rglru_init_state(batch, cfg.d_model, dtype)
         if kind == "rwkv":
-            return rwkv_init_state(batch, cfg.d_model, cfg.rwkv_head_dim)
+            return rwkv_init_state(batch, cfg.d_model, cfg.rwkv_head_dim,
+                                   dtype)
         raise ValueError(kind)
 
     out: Dict = {}

@@ -71,6 +71,15 @@ def _fi_step(fi: Optional[FaultConfig], step):
     return None if fi is None else fi.for_step(step)
 
 
+def _compute_dtype(params):
+    """The model's compute dtype (the embedding's; bf16 for an int8 one).
+
+    Cache slots are allocated in it: the layer scan writes each layer's
+    K/V and recurrent state into the carried stack, whose dtype is fixed
+    by its allocation."""
+    return getattr(params["embed"], "dtype", jnp.bfloat16)
+
+
 # --------------------------------------------------------------------------- #
 # runtime-fi steps (the engine path)
 # --------------------------------------------------------------------------- #
@@ -78,10 +87,11 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
     """(params, tokens, fi[, prefix_embeds/frames]) -> (logits_last, cache
     [, kv]).
 
-    The cache is allocated at ``max_len`` so subsequent decode steps reuse
-    it in place.  The final norm and the head run at the last prompt
-    position only: the one whose logits are sampled.  ``fi`` is a runtime argument (pytree) — one jitted
-    instance covers every device age of a fault flavour.
+    The cache is allocated at ``max_len``, in the model's compute dtype,
+    so subsequent decode steps reuse it in place.  The final norm and the
+    head run at the last prompt position only: the one whose logits are
+    sampled.  ``fi`` is a runtime argument (pytree) — one jitted instance
+    covers every device age of a fault flavour.
     """
     if cfg.n_encoder_layers:
         def prefill(params, tokens, fi, frames):
@@ -89,13 +99,8 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
             B, S = tokens.shape
             enc = encdec.encode(params, cfg, frames, fi=fi)
             kv = encdec.cross_kv(params, cfg, enc, fi=fi)
-            # cache slots must match the decoder's compute dtype (the
-            # params dtype): decoder-only prefill overwrites the whole
-            # cache so a mismatch is silently fixed there, but the enc-dec
-            # cache is written slot by slot
             cache = encdec.init_cache(cfg, B, max_len,
-                                      dtype=getattr(params["embed"], "dtype",
-                                                    jnp.bfloat16))
+                                      dtype=_compute_dtype(params))
             logits, cache = encdec.decode(
                 params, cfg, tokens, kv=kv, fi=fi, cache=cache,
                 cache_len=jnp.asarray(S, jnp.int32), last_only=True)
@@ -106,7 +111,8 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
         def prefill(params, tokens, fi, prefix_embeds):
             TRACE_COUNTS["prefill"] += 1
             B, S = tokens.shape
-            cache = tf.init_cache(cfg, B, max_len)
+            cache = tf.init_cache(cfg, B, max_len,
+                                  dtype=_compute_dtype(params))
             logits, cache, _ = tf.forward_logits(
                 params, cfg, tokens, states=cache,
                 cache_len=jnp.asarray(S + cfg.prefix_tokens, jnp.int32),
@@ -117,7 +123,8 @@ def make_prefill_fn(cfg: ModelConfig, max_len: int) -> Callable:
     def prefill(params, tokens, fi):
         TRACE_COUNTS["prefill"] += 1
         B, S = tokens.shape
-        cache = tf.init_cache(cfg, B, max_len)
+        cache = tf.init_cache(cfg, B, max_len,
+                              dtype=_compute_dtype(params))
         logits, cache, _ = tf.forward_logits(
             params, cfg, tokens, states=cache,
             cache_len=jnp.asarray(S, jnp.int32), fi=fi, last_only=True)
@@ -191,8 +198,10 @@ def make_generate_fn(cfg: ModelConfig, max_len: int, n_steps: int,
     Prefill, a ``lax.scan`` over ``n_steps - 1`` decode steps, and
     sampling all live in one trace:
 
-    * the KV cache never leaves the device or the trace — the scan carry
-      aliases it in place (XLA donates scan carries by construction);
+    * the KV cache never leaves the device or the trace: it lives in the
+      carry of both scans, the decode loop's and each step's layer scan,
+      and each decode step writes one slot per layer in place
+      (:func:`repro.models.transformer._run_blocks`);
     * sampling keys thread through the carry with one ``split`` per step
       — the same derivation the eager oracle performs, so token sequences
       are bit-exact between the two paths;

@@ -1,6 +1,8 @@
 """Continuous-batching online serving: chunked-vs-one-shot bit-exactness,
 slot-refill schedules, zero retrace across queue churn, admission control,
 and the measured-occupancy -> aging replay loop."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,6 +74,29 @@ def test_no_arrival_bit_exact_faulted(setup):
     res = eng.serve([Request(id=i, prompt=prompts[i], max_new=n_steps)
                      for i in range(K)],
                     greedy=False, temperature=0.7, eos_id=-1)
+    np.testing.assert_array_equal(ref, np.stack(_tokens_by_id(res)))
+
+
+def test_ragged_slots_bit_exact_past_the_window_wrap():
+    """Per-row slot depths through the cache scatter, past a windowed
+    ring's wrap: starcoder2's 16-slot window, slot 1 admitted a chunk after
+    slot 0, both decoding 12 tokens after an 8-token prompt.  Each request
+    greedy-generates what the one-shot static batch gives its row."""
+    cfg = dataclasses.replace(get_config("starcoder2_7b").reduced(),
+                              window=16)    # its sliding window, cut down
+    params = init_train_state(cfg, jax.random.PRNGKey(0)).params
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (2, S), 0, cfg.vocab), np.int32)
+    n_new = 12
+    assert S + n_new - 1 > cfg.window
+    ref = ServeEngine(cfg, params, max_len=MAX_LEN, seed=5).generate(
+        prompts, n_new).tokens
+    res = _online(cfg, params, n_slots=2, chunk=4).serve(
+        [Request(id=0, prompt=prompts[0], max_new=n_new, arrival=0),
+         Request(id=1, prompt=prompts[1], max_new=n_new, arrival=3)],
+        greedy=True, eos_id=-1)
+    by_id = {r.id: r for r in res.completed}
+    assert by_id[1].t_start > by_id[0].t_start      # ragged depths
     np.testing.assert_array_equal(ref, np.stack(_tokens_by_id(res)))
 
 

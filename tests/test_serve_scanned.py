@@ -1,5 +1,7 @@
 """Single-dispatch serving: scanned-vs-eager parity, in-graph sampling,
 compile-cache (zero retrace), and the fleet-vmapped engine."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,6 +85,28 @@ def test_scanned_matches_eager_three_pass_oracle(setups):
                              use_fused_kernel=False)
     a = mk().generate(prompts, 4, **extras)
     b = mk().generate(prompts, 4, scan=False, **extras)
+    np.testing.assert_array_equal(a.tokens, b.tokens)
+
+
+@pytest.mark.parametrize("route", ["clean", "faulted_fused"])
+def test_scanned_matches_eager_windowed_ring_wraps(route):
+    """A windowed ring past its wrap: starcoder2's 16-slot local window,
+    an 8-token prompt and 14 new tokens, so decode writes slot
+    ``(cache_len - 1) % 16`` of the carried stack for cache_len 17..21."""
+    cfg = dataclasses.replace(get_config("starcoder2_7b").reduced(),
+                              window=16)    # its sliding window, cut down
+    params = init_train_state(cfg, jax.random.PRNGKey(0)).params
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab), np.int32)
+    n_steps, max_len = 14, 24
+    assert prompts.shape[1] + n_steps - 1 > cfg.window
+    kw = {}
+    if route == "faulted_fused":
+        kw = dict(runtime=_aged_runtime(), use_systolic_kernel=True,
+                  use_fused_kernel=True)
+    mk = lambda: ServeEngine(cfg, params, max_len=max_len, seed=3, **kw)
+    a = mk().generate(prompts, n_steps, temperature=0.7)
+    b = mk().generate(prompts, n_steps, temperature=0.7, scan=False)
     np.testing.assert_array_equal(a.tokens, b.tokens)
 
 

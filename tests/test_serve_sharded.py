@@ -213,7 +213,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src")
-    import json
+    import dataclasses, json
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config
@@ -235,6 +235,16 @@ SHARDED_SCRIPT = textwrap.dedent("""
     host = jax.device_get(eng.params)
     b = ServeEngine(cfg, host, max_len=24, seed=3).generate(prompts, 5)
     out["clean_exact"] = bool(np.array_equal(a.tokens, b.tokens))
+
+    # a windowed ring past its wrap (16-slot window, cache_len up to 19)
+    wcfg = dataclasses.replace(get_config("starcoder2_7b").reduced(),
+                               window=16)
+    wparams = init_train_state(wcfg, jax.random.PRNGKey(0)).params
+    ew = MeshServeEngine(wcfg, wparams, max_len=24, seed=3)
+    aw = ew.generate(prompts, 12)
+    bw = ServeEngine(wcfg, jax.device_get(ew.params), max_len=24,
+                     seed=3).generate(prompts, 12)
+    out["window_exact"] = bool(np.array_equal(aw.tokens, bw.tokens))
 
     # uniform BER: sharded scalar-BER graph vs the single-device oracle
     rt = FleetRuntime(n_devices=1); rt.set_age(years=9.0)
@@ -271,11 +281,13 @@ SHARDED_SCRIPT = textwrap.dedent("""
 @pytest.mark.slow
 def test_sharded_generate_multidevice():
     """Sharded generation on 8 faked devices: bit-exact vs single device
-    (clean AND uniform-BER), per-shard BERs heterogeneous inside the one
-    dispatch, zero retrace across shard age changes."""
+    (clean, a windowed ring past its wrap, AND uniform-BER), per-shard BERs
+    heterogeneous inside the one dispatch, zero retrace across shard age
+    changes."""
     out = _run_script(SHARDED_SCRIPT, timeout=1500)
     assert out["tp"] == 8
     assert out["clean_exact"] is True
+    assert out["window_exact"] is True
     assert out["uniform_exact"] is True
     assert out["uniform_ber_max"] > 0          # end-of-life BERs were live
     assert out["zero_retrace"] is True
