@@ -233,6 +233,22 @@ def _write_token(buf, new, idx, layer):
         buf, new.reshape((1,) * len(lead) + new.shape), starts)
 
 
+def _read_ring(buf, new, idx, layer):
+    """Layer ``layer``'s ring ``(B, kv_len, ...)`` read from the written
+    stack ``buf``, with the token ``new`` selected in at slot ``idx``.
+
+    The select gives back what :func:`_write_token` just stored, so the
+    values are the stack's own.  It is there for XLA's TPU compiler, which
+    rewrites the faulted route's int8 QK^T and SV matvecs over a plain
+    slice of the carried stack into multiply-reduce loops on the vector
+    unit, far slower than the MXU dots it keeps over the select (PERF.md
+    Findings; ``tests/test_tpu_compile.py``)."""
+    ring = _layer_of(buf, layer)
+    slot = jnp.arange(ring.shape[1]) == jnp.reshape(idx, (-1, 1))
+    slot = slot.reshape(slot.shape + (1,) * (ring.ndim - 2))
+    return jnp.where(slot, new.astype(ring.dtype), ring)
+
+
 def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
                 cache=None, cache_len=None, fi=None, salt=0, layer=None):
     """Self-attention + FFN block.  With ``cache`` (decode): single token.
@@ -278,14 +294,14 @@ def _attn_block(x, bp, cfg: ModelConfig, *, positions, prefix_len,
             write = lambda buf, new: _write_token(buf, new, idx, layer)
             kc = jax.tree.map(write, cache["k"], knew)
             vc = jax.tree.map(write, cache["v"], vnew)
-            read = lambda buf: _layer_of(buf, layer)
+            read = lambda buf, new: _read_ring(buf, new, idx, layer)
+            k_at = jax.tree.map(read, kc, knew)
+            v_at = jax.tree.map(read, vc, vnew)
             if qcache:
-                k_at = read(kc["int8_q"]).astype(q.dtype) \
-                    * read(kc["int8_s"]).astype(q.dtype)
-                v_at = read(vc["int8_q"]).astype(q.dtype) \
-                    * read(vc["int8_s"]).astype(q.dtype)
-            else:
-                k_at, v_at = read(kc), read(vc)
+                k_at = k_at["int8_q"].astype(q.dtype) \
+                    * k_at["int8_s"].astype(q.dtype)
+                v_at = v_at["int8_q"].astype(q.dtype) \
+                    * v_at["int8_s"].astype(q.dtype)
             with jax.named_scope("attention"):
                 out = attn_lib.decode_attention(q, k_at, v_at, cache_len,
                                                 fi=fi, salt=salt)

@@ -13,6 +13,8 @@ process at a time may load the TPU library, and every test worker imports
 this file.  ``ops._default_interpret`` picks interpret mode from the CPU
 backend this process runs on, so each test steers it to the compiled path.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,8 +22,12 @@ import pytest
 from jax.sharding import (NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from repro.configs import ModelConfig
 from repro.distributed.sharding import make_mesh
 from repro.kernels import ops as kops
+from repro.models import transformer as tf
+from repro.models.layers import FaultConfig
+from repro.serve import steps
 from repro.kernels.bitflip import bitflip_words
 from repro.kernels.systolic_matmul import systolic_matmul
 
@@ -132,3 +138,34 @@ def test_shard_map_fused_route_compiles(compiled, topo):
              NamedSharding(mesh, P(None, "model"))),
         _sds((2,), jnp.float32, repl), _sds((), jnp.int32, repl))
     assert np.prod(mesh.devices.shape) == 4 and "all-gather" not in text
+
+
+def test_faulted_decode_attention_dots_stay_on_the_mxu(compiled, one_chip):
+    """The faulted decode step's int8 QK^T and SV matmuls, each a matvec
+    per (row, head) over a layer's ring read from the stacked cache, compile
+    to MXU dots (``kOutput`` fusions), not to multiply-reduce loops on the
+    vector unit.  Two MHA layers at the published widths over the chat
+    cells' 1,153-slot ring at batch 8; a one-layer scan compiles otherwise.
+    """
+    cfg = ModelConfig(name="deepseek_7b", family="dense", n_layers=2,
+                      d_model=D_MODEL, n_heads=32, n_kv_heads=32, d_ff=D_FF,
+                      vocab=1024, head_dim=128, mlp="gated", norm="rms",
+                      pos="rope")
+    batch, kv_len = 8, 1153
+    shapes = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), jax.eval_shape(tree))
+    params = shapes(lambda: tf.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: tf.init_cache(cfg, batch, kv_len))
+    fi = shapes(lambda: FaultConfig(
+        bers={op: jnp.float32(1e-5) for op in ("q", "k", "v", "qkt", "sv")},
+        key=jax.random.PRNGKey(0), step=jnp.int32(0)))
+    text = jax.jit(steps.make_decode_fn(cfg), donate_argnums=(2,)).lower(
+        params, _sds((batch, 1), jnp.int32, one_chip), cache,
+        _sds((), jnp.int32, one_chip), fi).compile().as_text()
+    roots = dict(re.findall(r"^%([\w.\-]+) .*\{\n(?:.*\n)*?\s*ROOT %\S+ = "
+                            r"\S+ ([\w-]+)\(", text, re.M))
+    dots = re.findall(r"^\s*%\S+ = \S+ fusion\(.*kind=(\w+), "
+                      r"calls=%([\w.\-]+).*attention/\.\.\.ik,\.\.\.kj->"
+                      r"\.\.\.ij/dot_general", text, re.M)
+    assert [kind for kind, _ in dots].count("kOutput") == 2, dots
+    assert not [c for _, c in dots if roots.get(c) == "reduce"], dots
